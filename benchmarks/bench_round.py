@@ -13,15 +13,6 @@ several repetitions and the per-mode minimum is reported (robust to the
 noisy-neighbor variance of this shared container — spreads of 2–3× between
 repetitions were observed on idle cores).
 
-XLA:CPU runtime note (measured here, recorded in ROADMAP): the default
-thunk runtime executes compiled-loop bodies on a serial path — the SAME
-round costs ~1.6× more inside a lax.scan than as a standalone jit, and the
-sharded runtime's collectives degrade ~10×. This module therefore pins
-``--xla_cpu_use_thunk_runtime=false`` (set below, before jax initializes)
-for BOTH the before and after modes, so the comparison isolates
-chunking+donation rather than the runtime regression. TPU is unaffected
-(the thunk runtime is CPU-only).
-
 Three timed modes per (algo × runtime × channel × local_impl) cell:
 
   seed_loop — faithful re-enactment of the seed per-round loop: jit dispatch
@@ -59,32 +50,26 @@ tests/test_local_update.py) plus the ops-level trajectory parity.
 """
 from __future__ import annotations
 
-# BEFORE any jax import — see the XLA:CPU runtime note in the docstring.
+import json
 import os
+import sys
+import time
 
-XLA_CPU_FLAG = "--xla_cpu_use_thunk_runtime=false"
-if XLA_CPU_FLAG not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (
-        XLA_CPU_FLAG + " " + os.environ.get("XLA_FLAGS", "")).strip()
+import jax
+import numpy as np
 
-import json      # noqa: E402
-import sys       # noqa: E402
-import time      # noqa: E402
-
-import jax       # noqa: E402
-import numpy as np  # noqa: E402
-
-from repro.core import (  # noqa: E402
+from repro.core import (
     AlgoHParams,
     init_state,
     make_chunk_runner,
     make_round_fn,
 )
-from repro.core.sharded import make_sharded_round_fn  # noqa: E402
-from repro.launch.mesh import make_host_mesh          # noqa: E402
-from repro.utils import tree_math as tm               # noqa: E402
+from repro.core.sharded import make_sharded_round_fn
+from repro.launch.mesh import make_host_mesh
+from repro.utils import tree_math as tm
+from repro.utils.compile_cache import use_compile_cache
 
-from benchmarks.common import logreg_setup            # noqa: E402
+from benchmarks.common import logreg_setup
 
 #: the committed perf-trajectory artifact (full grid only; see SMOKE_PATH)
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_round.json")
@@ -384,7 +369,6 @@ def run(smoke: bool = False) -> dict:
         "setup": {"dataset": "covtype-quick", "n": 10_000, "k": 10,
                   "eta": 1.0, "local_epochs": 10,
                   "backend": jax.default_backend(),
-                  "xla_flags": os.environ.get("XLA_FLAGS", ""),
                   "timing": "interleaved reps, per-mode min",
                   "timestamp": time.strftime("%Y-%m-%dT%H:%M:%S")},
         "smoke": smoke,
@@ -408,5 +392,10 @@ def run(smoke: bool = False) -> dict:
     return out
 
 
-if __name__ == "__main__":
+def main() -> None:
+    use_compile_cache()
     run(smoke="--smoke" in sys.argv)
+
+
+if __name__ == "__main__":
+    main()

@@ -11,7 +11,7 @@ against the dense all-K round at each K.
 Two quantities per (K, mode) cell, both on the engine path:
 
   ms/round        — warm wall-time, interleaved reps, per-mode min (the
-                    bench_round.py methodology; same thunk-runtime pin);
+                    bench_round.py methodology);
   peak live bytes — XLA's own compiled-memory analysis of the chunk
                     executable (argument + output + temp − aliased), i.e.
                     what the compiled round body actually holds live. The
@@ -24,39 +24,30 @@ round would cost without the cohort axis. Full runs commit the sweep to
 ``benchmarks/results/ext_cohort.json``; ``--smoke`` (the CI gate) runs a
 reduced sweep to a scratch path so it never clobbers the committed numbers.
 
-Standalone (the XLA flag must precede jax init, so this module is not part
-of benchmarks/run.py's MODULES):
+Standalone (not part of benchmarks/run.py's MODULES):
 
   PYTHONPATH=src python -m benchmarks.ext_cohort           # full sweep
   PYTHONPATH=src python -m benchmarks.ext_cohort --smoke   # CI gate
 """
 from __future__ import annotations
 
-# BEFORE any jax import — the thunk runtime serializes compiled-loop bodies
-# on CPU (see bench_round.py's XLA:CPU runtime note; measured in ROADMAP).
+import argparse
+import json
 import os
+import time
 
-XLA_CPU_FLAG = "--xla_cpu_use_thunk_runtime=false"
-if XLA_CPU_FLAG not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (
-        XLA_CPU_FLAG + " " + os.environ.get("XLA_FLAGS", "")).strip()
+import jax
+import numpy as np
 
-import argparse  # noqa: E402
-import json      # noqa: E402
-import time      # noqa: E402
-
-import jax       # noqa: E402
-import numpy as np  # noqa: E402
-
-from repro.core import (  # noqa: E402
+from repro.core import (
     AlgoHParams,
     init_state,
     make_chunk_runner,
     make_round_fn,
     solve_reference,
 )
-from repro.data import make_binary_classification, partition  # noqa: E402
-from repro.models.logreg import make_logreg_problem           # noqa: E402
+from repro.data import make_binary_classification, partition
+from repro.models.logreg import make_logreg_problem
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "results")
 #: the committed sweep (full mode only)
@@ -168,7 +159,6 @@ def run(smoke: bool = False) -> dict:
                   "dataset": "synthetic_small", "samples_per_client": 8,
                   "eta": 0.5, "local_epochs": 2,
                   "backend": jax.default_backend(),
-                  "xla_flags": os.environ.get("XLA_FLAGS", ""),
                   "timing": "interleaved reps, per-mode min",
                   "timestamp": time.strftime("%Y-%m-%dT%H:%M:%S")},
         "smoke": smoke,

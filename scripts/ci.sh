@@ -73,12 +73,6 @@ python scripts/check_ext_robustness.py benchmarks/results/ext_robustness.json
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m benchmarks.ext_async --smoke
 python scripts/check_ext_async.py benchmarks/results/ext_async.json
 
-# XLA:CPU thunk-runtime loop-body repro (ROADMAP item): records the
-# scan-body penalty of the default runtime vs the legacy one — the artifact
-# to attach upstream and to re-check on jaxlib upgrades. Not gated on a
-# threshold (jaxlib-version dependent).
-python scripts/repro_thunk_runtime.py --smoke
-
 # Telemetry smoke (repro/obs): a chunked engine run streams per-round rows
 # to a JSONL sink (with the default health monitors attached), then the
 # schema validator checks the versioned header/round/footer contract — so a

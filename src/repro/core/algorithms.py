@@ -394,7 +394,11 @@ def _local_trajectory(
 
     def step(w, step_rng):
         r = residual_fn(w, step_rng)
-        return tm.tree_axpy(-hp.eta, r, w), (w, r)
+        # the residual may carry f32 terms (the aggregated global gradient)
+        # into bf16 params; the iterate keeps the params' dtype
+        w_next = jax.tree.map(lambda a, b: a.astype(b.dtype),
+                              tm.tree_axpy(-hp.eta, r, w), w)
+        return w_next, (w, r)
 
     if hp.local_impl == LOCAL_IMPL_SEED:
         # the seed form, replayed for bench_round's baseline: scan stops at
@@ -782,13 +786,18 @@ def _aggregate(weights: jax.Array, stacked: Pytree, anchor: Pytree | None = None
 
     When ``anchor`` is given, uses the delta form anchor + Σ w_k(x_k − anchor):
     identical when Σweights = 1, and degrades to a no-op (instead of zeroing
-    the model) if a partial-participation round draws no clients.
+    the model) if a partial-participation round draws no clients. The sum
+    accumulates in at least the weights' f32 and returns in each leaf's own
+    dtype, so bf16 params stay bf16.
     """
     if anchor is None:
-        return jax.tree.map(lambda s: jnp.tensordot(weights, s, axes=1), stacked)
+        return jax.tree.map(
+            lambda s: jnp.tensordot(weights, s, axes=1).astype(s.dtype),
+            stacked)
     return jax.tree.map(
-        lambda a, s: a + jnp.tensordot(weights, s - a[None], axes=1), anchor, stacked
-    )
+        lambda a, s: (a + jnp.tensordot(weights, s - a[None], axes=1)
+                      ).astype(a.dtype),
+        anchor, stacked)
 
 
 class CrossClientReduce:

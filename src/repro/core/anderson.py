@@ -282,10 +282,13 @@ def _multisecant_update_pallas(
     y_leaves = jax.tree.leaves(y_stack)
     m = y_leaves[0].shape[0]
     groups = ops.dtype_leaf_groups(w)
+    # the Gram system accumulates in at least f32, f64 groups in f64 (as
+    # tree_math does on the tree path)
+    acc = jnp.result_type(jnp.float32, *(dt for dt, _ in groups))
 
-    gram = jnp.zeros((m, m), jnp.float32)
-    yg = jnp.zeros((m,), jnp.float32)
-    g_norm2 = jnp.zeros((), jnp.float32)
+    gram = jnp.zeros((m, m), acc)
+    yg = jnp.zeros((m,), acc)
+    g_norm2 = jnp.zeros((), acc)
     flats = []
     for _, idxs in groups:
         wf = ops.ravel_group(w_leaves, idxs)
@@ -293,10 +296,10 @@ def _multisecant_update_pallas(
         sf = ops.ravel_stack_group(s_leaves, idxs)
         yf = ops.ravel_stack_group(y_leaves, idxs)
         gm, ygv = ops.flat_gram(yf, gf)
-        gram += gm
-        yg += ygv
-        gf32 = gf.astype(jnp.float32)
-        g_norm2 += jnp.dot(gf32, gf32)
+        gram += gm.astype(acc)
+        yg += ygv.astype(acc)
+        ga = gf.astype(acc)
+        g_norm2 += jnp.dot(ga, ga)
         flats.append((idxs, wf, gf, sf, yf))
 
     gamma, cond, used, clipped, keep = _screened_solve(gram, yg, cfg)

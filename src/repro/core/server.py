@@ -43,6 +43,8 @@ class History:
                                   # everywhere when async_cfg is off)
     staleness_mean: np.ndarray = None  # mean landed buffer age (nan if n/a)
     staleness_max: np.ndarray = None   # oldest landed buffer age (nan if n/a)
+    aa_used_min: np.ndarray = None     # fewest AA columns surviving filtering
+                                       # on any client (nan for non-AA algos)
 
     @property
     def comm_floats(self) -> np.ndarray:
@@ -115,8 +117,8 @@ def run_federated(
     runtime — "vmap" (default): the K clients are vmapped on one device;
               "sharded": the client fan-out runs under shard_map over the
               ("pod","data") axes of ``mesh`` (core/sharded.py). ``mesh``
-              defaults to launch/mesh.py::make_host_mesh() so the sharded
-              runtime is exercisable on a 1-device CPU.
+              defaults to launch/mesh.py::make_host_mesh(): every device of
+              the host on "data" (1×1 on one chip or a CPU).
     channel — repro/comm wire-compression channel (a CommChannel or a spec
               string like "int8", "topk:0.05", "bf16/bf16"); None = lossless
               fp32. Both runtimes honor it, and ``History.comm_bytes`` counts
@@ -307,6 +309,7 @@ def run_federated(
             arrivals=trace.arrivals,
             staleness_mean=trace.staleness_mean,
             staleness_max=trace.staleness_max,
+            aa_used_min=trace.aa_used_min,
         )
 
     round_fn = jax.jit(round_fn)
@@ -350,7 +353,8 @@ def run_federated(
             rows.append((t, mdict["loss"], mdict["grad_norm"], rel,
                          mdict["theta_mean"], mdict["gram_cond_max"],
                          comm_total, t_total, mdict["arrivals"],
-                         mdict["staleness_mean"], mdict["staleness_max"]))
+                         mdict["staleness_mean"], mdict["staleness_max"],
+                         mdict["aa_used_min"]))
             for s in sinks:
                 s.emit([build_round_row(t, mdict, rel, comm_total, dt,
                                         t_total)])
@@ -390,7 +394,7 @@ def run_federated(
     arr = np.asarray(rows, dtype=np.float64)
     if arr.size == 0:
         # resumed at (or past) the round budget: nothing left to run
-        arr = arr.reshape(0, 11)
+        arr = arr.reshape(0, 12)
     return History(
         algo=algo,
         rounds=arr[:, 0],
@@ -406,6 +410,7 @@ def run_federated(
         arrivals=arr[:, 8],
         staleness_mean=arr[:, 9],
         staleness_max=arr[:, 10],
+        aa_used_min=arr[:, 11],
     )
 
 

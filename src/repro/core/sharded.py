@@ -63,7 +63,6 @@ from repro.core.algorithms import (
 )
 from repro.core.anderson import resolve_aa_impl
 from repro.core.problem import FLProblem
-from repro.utils.compat import shard_map
 
 #: mesh axes the client axis is partitioned over, slowest (inter-pod) first.
 CLIENT_MESH_AXES = ("pod", "data")
@@ -84,16 +83,18 @@ class ShardReduce(CrossClientReduce):
 
     def wsum(self, weights, stacked, anchor=None):
         with jax.named_scope("fl.psum"):
+            # in each leaf's own dtype, as algorithms._aggregate
             if anchor is None:
                 return jax.tree.map(
                     lambda s: jax.lax.psum(
-                        jnp.tensordot(weights, s, axes=1), self.axes),
+                        jnp.tensordot(weights, s, axes=1), self.axes
+                    ).astype(s.dtype),
                     stacked,
                 )
             return jax.tree.map(
-                lambda a, s: a + jax.lax.psum(
+                lambda a, s: (a + jax.lax.psum(
                     jnp.tensordot(weights, s - a[None], axes=1), self.axes
-                ),
+                )).astype(a.dtype),
                 anchor, stacked,
             )
 
@@ -275,9 +276,9 @@ def make_sharded_round_fn(algo: str, problem: FLProblem, hp: AlgoHParams,
 
     def smap(body, in_specs, out_specs):
         # check_vma off: the bodies close over `problem`/`hp` and batch psums
-        # under vmap (line search), which older jax replication checks reject.
-        return shard_map(body, mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_vma=False)
+        # under vmap (line search), which the replication check rejects.
+        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     # ---------------- fault injection (repro/robust) ----------------
     # python-gated exactly like the vmap builder: an absent/inactive plan

@@ -1,12 +1,11 @@
 """Jit'd public wrappers: flat-vector AA passes via the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode; on TPU they
-compile natively. The wrappers pad d up to the tile size and m up to the
-8-sublane granule (histories longer than one granule — m > 8, e.g. L=10
-local epochs or carried cross-round columns — pad to the next multiple of
-8), then strip the padding: padded Y columns are zero so they contribute
-nothing to the Gram matrix, and gamma entries for them are zeroed after the
-solve.
+Off a TPU the kernels execute in interpret mode; on a TPU they compile
+natively. The wrappers pad d up to a tile multiple and strip the padding
+after: padded Y entries are zero, so they contribute nothing to the Gram
+matrix. The history axis m is never padded — every block spans all m rows,
+which Mosaic accepts for any m, so a [m, d] history is not copied into an
+8-row buffer (at m=2 that copy would quadruple the history bytes).
 
 Besides the one-shot ``aa_step_flat`` (kept as the flat-vector reference
 entry point), this module exposes the two passes separately
@@ -46,13 +45,11 @@ def _pad_to(x, n, axis):
     return jnp.pad(x, widths)
 
 
-def _pad_dims(m: int, d: int, tile: int) -> tuple[int, int, int]:
-    """(tile, d_pad, m_pad): shrink the tile for small vectors, pad d to a
-    tile multiple and m to the 8-sublane granule (handles m > 8)."""
+def _pad_dims(d: int, tile: int) -> tuple[int, int]:
+    """(tile, d_pad): shrink the tile for small vectors, pad d to a tile
+    multiple."""
     t = min(tile, 256) if d < tile else tile
-    d_pad = ((d + t - 1) // t) * t
-    m_pad = ((m + 7) // 8) * 8
-    return t, d_pad, m_pad
+    return t, ((d + t - 1) // t) * t
 
 
 # --------------------------------------------------------------------------
@@ -64,12 +61,9 @@ def flat_gram(y, g, *, tile: int = DEFAULT_TILE, interpret: bool | None = None):
     (YᵀY [m,m] f32, Yᵀg [m] f32). Pads internally; any m ≥ 1."""
     if interpret is None:
         interpret = _interpret_default()
-    m, d = y.shape
-    t, d_pad, m_pad = _pad_dims(m, d, tile)
-    yp = _pad_to(_pad_to(y, d_pad, 1), m_pad, 0)
-    gp = _pad_to(g, d_pad, 0)
-    gram, yg = gram_pallas(yp, gp, tile=t, interpret=interpret)
-    return gram[:m, :m], yg[:m]
+    t, d_pad = _pad_dims(y.shape[1], tile)
+    return gram_pallas(_pad_to(y, d_pad, 1), _pad_to(g, d_pad, 0), tile=t,
+                       interpret=interpret)
 
 
 def flat_update(w, g, s, y, gamma, eta, beta, *, tile: int = DEFAULT_TILE,
@@ -78,14 +72,11 @@ def flat_update(w, g, s, y, gamma, eta, beta, *, tile: int = DEFAULT_TILE,
     w,g: [d]; s,y: [m,d]; gamma: [m]. Pads internally; preserves w.dtype."""
     if interpret is None:
         interpret = _interpret_default()
-    m, d = s.shape
-    t, d_pad, m_pad = _pad_dims(m, d, tile)
-    wp, gp = _pad_to(w, d_pad, 0), _pad_to(g, d_pad, 0)
-    sp = _pad_to(_pad_to(s, d_pad, 1), m_pad, 0)
-    yp = _pad_to(_pad_to(y, d_pad, 1), m_pad, 0)
-    gp_ = _pad_to(gamma.astype(jnp.float32), m_pad, 0)
-    out = update_pallas(wp, gp, sp, yp, gp_, eta, beta, tile=t,
-                        interpret=interpret)
+    d = s.shape[1]
+    t, d_pad = _pad_dims(d, tile)
+    out = update_pallas(_pad_to(w, d_pad, 0), _pad_to(g, d_pad, 0),
+                        _pad_to(s, d_pad, 1), _pad_to(y, d_pad, 1), gamma,
+                        eta, beta, tile=t, interpret=interpret)
     return out[:d]
 
 
@@ -133,8 +124,6 @@ def aa_step_flat(w, g, s, y, *, eta: float, beta: float = 1.0,
     """One AA step on flat vectors. w,g: [d]; s,y: [m,d]. Returns w⁺ [d]."""
     if interpret is None:
         interpret = _interpret_default()
-    # solve only over the true m columns (padded rows/cols are zero; the
-    # padded gamma entries flat_update re-pads are zero too)
     gram, yg = flat_gram(y, g, tile=tile, interpret=interpret)
     gamma = solve_gamma_ref(gram, yg, tikhonov)
     return flat_update(w, g, s, y, gamma, eta, beta, tile=tile,

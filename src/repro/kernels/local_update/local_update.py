@@ -21,22 +21,24 @@ the L-step loop runs on-chip with X resident.
 
 Layout (one client; the round cores vmap this over K):
 
-    x:    [S·n, d]   design blocks, S stacked on the row axis — S == 1
-                     (full batch: every step revisits block 0, which is
-                     what keeps it resident) or S == steps (per-step
-                     minibatch gathers).  Kept 2-D: the row tile is a plain
-                     (row_tile, d) block, bit-identical to the oracle's
-                     contractions (a squeezed 3-D block is not)
-    y:    [S, n]     targets (±1 for the logistic link)
-    mask: [S, n]     0/1 row validity (padded rows contribute exactly 0)
-    w0:   [1, d]     start == anchor w^t
-    u:    [1, d]     constant additive correction (see above)
-    invn: [1, 1]     1 / n_eff (the loss's masked-mean denominator)
+    x:    [S·n, d]     design blocks, S stacked on the row axis — S == 1
+                       (full batch: every step revisits block 0, which is
+                       what keeps it resident) or S == steps (per-step
+                       minibatch gathers).  Kept 2-D: the row tile is a
+                       plain (row_tile, d) block
+    y:    [S, 1, n]    targets (±1 for the logistic link)
+    mask: [S, 1, n]    0/1 row validity (padded rows contribute exactly 0)
+    w0:   [1, d]       start == anchor w^t
+    u:    [1, d]       constant additive correction (see above)
+    invn: [1, 1]       1 / n_eff (the loss's masked-mean denominator)
 
 Grid is (steps, row_tiles) — row tiles iterate fastest; a VMEM scratch pair
 (w_cur, acc) carries the iterate and the gradient accumulator across grid
-steps, and the (w_traj, r_traj) history FedOSAA's AA step consumes is
-emitted tile-block-locally at the last row tile of every step.
+steps.  Every block's last two dims are (8, 128)-aligned or span the whole
+array, as Mosaic requires: y/mask carry a unit middle axis so a step's
+(1, row_tile) slice is a full-height block, and the [steps, d] (w_traj,
+r_traj) outputs are ONE block resident across the grid, written one row at
+the last row tile of every step and flushed to HBM once.
 
 The [steps, d] trajectories, w_cur and the dual logit/coefficient buffers
 live in VMEM; only X (once per step, at worst) and the emitted trajectory
@@ -54,6 +56,17 @@ LINKS = ("logistic", "linear")
 
 #: default row-tile height (lane-granule multiple; see ops.py for sizing)
 DEFAULT_ROW_TILE = 512
+
+#: f32 contractions at full f32 precision. At Mosaic's default an f32 dot
+#: rounds its operands to bf16: the logits, and so the local fixed point,
+#: drift, and FedOSAA-SVRG on the paper covtype split (K=100) bottomed out
+#: at rel-error 9e-3 on a v5e instead of 1.1e-5.
+PRECISION = jax.lax.Precision.HIGHEST
+
+#: scoped-VMEM limit of one kernel call. At PRECISION the resident K=100
+#: covtype block (5,888x128 f32, double-buffered) needs 18.9 MB of scoped
+#: VMEM, past Mosaic's 16 MB default; a v5e core has 128 MiB.
+VMEM_LIMIT = 64 * 1024 * 1024
 
 
 def link_coeff(link: str, z: jax.Array, y: jax.Array, mask: jax.Array):
@@ -77,9 +90,10 @@ def _make_traj_kernel(link: str, eta: float, reg: float, anchor: bool,
 
     def kernel(x_ref, y_ref, m_ref, w0_ref, u_ref, invn_ref,
                wt_ref, rt_ref, wcur, acc):
+        step = pl.program_id(0)
         i = pl.program_id(1)
         n_tiles = pl.num_programs(1)
-        first = jnp.logical_and(pl.program_id(0) == 0, i == 0)
+        first = jnp.logical_and(step == 0, i == 0)
 
         @pl.when(first)
         def _init():
@@ -96,7 +110,7 @@ def _make_traj_kernel(link: str, eta: float, reg: float, anchor: bool,
 
         # forward: live logits from the tile already in VMEM ...
         z = jax.lax.dot_general(
-            w, x, (((1,), (1,)), ((), ())),
+            w, x, (((1,), (1,)), ((), ())), precision=PRECISION,
             preferred_element_type=compute_dtype)   # [1, Tn]
         c = link_coeff(link, z, yv, mv)
         if anchor:
@@ -104,13 +118,13 @@ def _make_traj_kernel(link: str, eta: float, reg: float, anchor: bool,
             # gradient of the dual-gradient residual costs no extra X fetch
             z0 = jax.lax.dot_general(
                 w0_ref[...].astype(compute_dtype), x,
-                (((1,), (1,)), ((), ())),
+                (((1,), (1,)), ((), ())), precision=PRECISION,
                 preferred_element_type=compute_dtype)
             c = c - link_coeff(link, z0, yv, mv)
         # one combined backward accumulation: both residual contributions
         # ride a single Xᵀ(·) sweep of the tile
         acc[...] += jax.lax.dot_general(
-            c, x, (((1,), (0,)), ((), ())),
+            c, x, (((1,), (0,)), ((), ())), precision=PRECISION,
             preferred_element_type=compute_dtype)   # [1, d]
 
         @pl.when(i == n_tiles - 1)
@@ -118,8 +132,9 @@ def _make_traj_kernel(link: str, eta: float, reg: float, anchor: bool,
             w_now = wcur[...]
             r = (acc[...] * invn_ref[0, 0].astype(compute_dtype)
                  + reg * w_now + u_ref[...].astype(compute_dtype))
-            wt_ref[...] = w_now.astype(wt_ref.dtype)
-            rt_ref[...] = r.astype(rt_ref.dtype)
+            row = pl.ds(step, 1)
+            wt_ref[row, :] = w_now.astype(wt_ref.dtype)
+            rt_ref[row, :] = r.astype(rt_ref.dtype)
             wcur[...] = w_now - eta * r
 
     return kernel
@@ -129,14 +144,14 @@ def trajectory_pallas(x, y, mask, w0, u, invn, *, link: str, eta: float,
                       reg: float, anchor_scale: float, steps: int,
                       row_tile: int = DEFAULT_ROW_TILE,
                       interpret: bool = False):
-    """x: [S·n, d] (S stacked on rows); y, mask: [S, n]; w0, u: [1, d];
+    """x: [S·n, d] (S stacked on rows); y, mask: [S, 1, n]; w0, u: [1, d];
     invn: [1, 1].
 
     S must be 1 (resident full-batch design) or ``steps`` (per-step
     minibatch blocks); n % row_tile == 0.  Returns (w_traj, r_traj), each
     [steps, d] in w0.dtype.
     """
-    S, n = y.shape
+    S, _, n = y.shape
     d = x.shape[1]
     if x.shape[0] != S * n:
         raise ValueError(f"x rows {x.shape[0]} != S*n = {S}*{n}")
@@ -157,15 +172,17 @@ def trajectory_pallas(x, y, mask, w0, u, invn, *, link: str, eta: float,
         in_specs=[
             pl.BlockSpec((row_tile, d),
                          lambda l, i: (sidx(l) * n_tiles + i, 0)),
-            pl.BlockSpec((1, row_tile), lambda l, i: (sidx(l), i)),
-            pl.BlockSpec((1, row_tile), lambda l, i: (sidx(l), i)),
+            pl.BlockSpec((pl.squeezed, 1, row_tile),
+                         lambda l, i: (sidx(l), 0, i)),
+            pl.BlockSpec((pl.squeezed, 1, row_tile),
+                         lambda l, i: (sidx(l), 0, i)),
             pl.BlockSpec((1, d), lambda l, i: (0, 0)),
             pl.BlockSpec((1, d), lambda l, i: (0, 0)),
             pl.BlockSpec((1, 1), lambda l, i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, d), lambda l, i: (l, 0)),
-            pl.BlockSpec((1, d), lambda l, i: (l, 0)),
+            pl.BlockSpec((steps, d), lambda l, i: (0, 0)),
+            pl.BlockSpec((steps, d), lambda l, i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((steps, d), w0.dtype),
@@ -175,6 +192,7 @@ def trajectory_pallas(x, y, mask, w0, u, invn, *, link: str, eta: float,
             pltpu.VMEM((1, d), compute_dtype),   # w_cur
             pltpu.VMEM((1, d), compute_dtype),   # gradient accumulator
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(x, y, mask, w0, u, invn)
     return w_traj, r_traj

@@ -58,14 +58,14 @@ def _granule(v: int, g: int = 128) -> int:
 
 def _pick_row_tile(S: int, n_pad: int, d_pad: int, itemsize: int) -> int:
     """Row-tile height: the whole block when it fits the resident budget
-    (S==1 → X stays in VMEM across every local step), else the largest
-    128-granule tile inside the per-tile budget."""
+    (S==1 → X stays in VMEM across every local step), else the fewest
+    128-granule tiles inside the per-tile budget, balanced so the rows
+    padded onto the last tile stay under one granule per tile."""
     if S == 1 and n_pad * d_pad * itemsize <= RESIDENT_BUDGET:
         return n_pad
-    t = max(128, (TILE_BUDGET // max(d_pad * itemsize, 1)) // 128 * 128)
-    while n_pad % t:
-        t -= 128
-    return max(t, 128)
+    t_max = max(128, (TILE_BUDGET // max(d_pad * itemsize, 1)) // 128 * 128)
+    tiles = -(-n_pad // t_max)
+    return _granule(-(-n_pad // tiles))
 
 
 def _pad_axis(a, n, axis):
@@ -114,8 +114,8 @@ def fused_trajectory(x, y, mask, w0, u, *, link: str, reg: float, eta: float,
         row_tile = _pick_row_tile(S, n_pad, d_pad, x.dtype.itemsize)
     n_pad = _granule(n_pad, row_tile)
     xp = _pad_axis(_pad_axis(x, n_pad, 1), d_pad, 2).reshape(S * n_pad, d_pad)
-    yp = _pad_axis(y, n_pad, 1)
-    mp = _pad_axis(mask, n_pad, 1)
+    yp = _pad_axis(y, n_pad, 1)[:, None, :]
+    mp = _pad_axis(mask, n_pad, 1)[:, None, :]
     w_traj, r_traj = trajectory_pallas(
         xp, yp, mp, _pad_axis(w0r, d_pad, 1), _pad_axis(ur, d_pad, 1), invn,
         link=link, eta=eta, reg=reg, anchor_scale=anchor_scale, steps=steps,

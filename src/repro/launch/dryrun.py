@@ -17,12 +17,15 @@ Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --fl-round all --multi-pod
 """
 # The VERY FIRST lines, before ANY other import: jax locks the device count
-# at first init, and the dry-run needs 512 placeholder host devices.
+# at first init, and the dry-run needs 512 placeholder host devices. Only
+# when run as the CLI: a process that imports a helper from here keeps its
+# own device count.
 import os
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 "
-    + os.environ.get("XLA_FLAGS", "")
-)
+if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=512 "
+        + os.environ.get("XLA_FLAGS", "")
+    )
 
 import argparse          # noqa: E402
 import json              # noqa: E402
@@ -89,12 +92,8 @@ def collective_bytes(hlo_text: str) -> dict:
 
 
 def _cost_dict(compiled) -> dict:
-    """compiled.cost_analysis(), normalized: older jax returns one dict per
-    program in a list, newer returns the dict directly."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """compiled.cost_analysis(), or {} where the backend reports none."""
+    return compiled.cost_analysis() or {}
 
 
 def _named(tree_specs, mesh):

@@ -23,9 +23,13 @@ from repro.core import AlgoHParams, run_federated
 from repro.core.lm import make_lm_clients, make_lm_problem
 from repro.data import make_lm_tokens
 from repro.models.decoder import build_model
+from repro.utils.compile_cache import use_compile_cache
 
 
-def main() -> None:
+def main(argv: "list[str] | None" = None) -> dict:
+    """Parse ``argv`` (default: sys.argv), train, and return the per-algo
+    results dict that ``--out`` writes."""
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -198,7 +202,7 @@ def main() -> None:
                     help="arm on-demand tracing: touching this file while "
                          "the run is in flight traces the next chunk (the "
                          "file is consumed per window)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_arch(args.arch)
     if args.reduced:
@@ -326,6 +330,7 @@ def main() -> None:
             "loss_curve": [float(v) for v in h.loss],
             "grad_norm_curve": [float(v) for v in h.grad_norm],
             "gram_cond_curve": [float(v) for v in h.gram_cond_max],
+            "aa_used_min_curve": [float(v) for v in h.aa_used_min],
             "comm_bytes": float(h.comm_bytes[-1]),
             "channel": h.channel,
             "wall_s": time.time() - t0,
@@ -356,6 +361,7 @@ def main() -> None:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
         with open(args.out, "w") as f:
             json.dump(results, f, indent=1)
+    return results
 
 
 if __name__ == "__main__":

@@ -1,12 +1,20 @@
 """Production mesh construction (deliverable e).
 
 A FUNCTION, not a module-level constant — importing this module never touches
-jax device state (smoke tests must see 1 CPU device; only dryrun.py sets
+jax device state (only the dryrun CLI sets
 XLA_FLAGS=--xla_force_host_platform_device_count=512 before first jax init).
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    # Auto axes: the round code leaves resharding (the cohort gather/scatter
+    # around shard_map) to the partitioner. jax.make_mesh defaults to
+    # Explicit axes, under which that scatter is a type error.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,9 +25,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh():
-    """1-device mesh for CPU smoke paths that still exercise pjit."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    """Client mesh over every device of this host: ("data"=n, "model"=1).
+
+    One chip (or a CPU test process) gives a 1×1 mesh on which the sharded
+    runtime's psums are identities; a 4-chip v5e host partitions the clients
+    four ways. Pod-scale jobs (≥ 256 devices) use make_production_mesh."""
+    return _mesh((jax.device_count(), 1), ("data", "model"))

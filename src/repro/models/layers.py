@@ -399,7 +399,6 @@ def moe_sharded(p: dict, x: jax.Array, cfg, sh: Sharder,
         batch_ax, batch_tuple = None, ()
 
     from jax.sharding import PartitionSpec as P
-    from repro.utils.compat import shard_map
 
     def local_fn(router_w, wig, wiu, wo, xl):
         Bl, Sl, d = xl.shape
@@ -446,7 +445,7 @@ def moe_sharded(p: dict, x: jax.Array, cfg, sh: Sharder,
         return y.reshape(Bl, Sl, d), aux
 
     ba = batch_ax
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(), P("model", None, None), P("model", None, None),

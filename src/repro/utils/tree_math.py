@@ -46,11 +46,16 @@ def tree_axpy(alpha, x: Pytree, y: Pytree) -> Pytree:
     return jax.tree.map(lambda xi, yi: alpha * xi + yi, x, y)
 
 
+def _acc(x: jax.Array) -> jax.Array:
+    """Cast to the accumulation dtype: at least f32 (bf16/f16 widen), and
+    f64 stays f64 — an f64 run's AA Gram must not round through f32, whose
+    noise the Gram solve amplifies by its condition number."""
+    return x.astype(jnp.promote_types(x.dtype, jnp.float32))
+
+
 def tree_dot(a: Pytree, b: Pytree) -> jax.Array:
-    """<a, b> over all leaves, accumulated in f32."""
-    leaves = jax.tree.map(
-        lambda x, y: jnp.sum(x.astype(jnp.float32) * y.astype(jnp.float32)), a, b
-    )
+    """<a, b> over all leaves, accumulated in at least f32."""
+    leaves = jax.tree.map(lambda x, y: jnp.sum(_acc(x) * _acc(y)), a, b)
     return _sum_leaves(jax.tree.leaves(leaves))
 
 
@@ -66,10 +71,8 @@ def tree_vdot_stacked(stack: Pytree, v: Pytree) -> jax.Array:
     """
     def leaf(s, x):
         axes = list(range(1, s.ndim))
-        return jnp.tensordot(
-            s.astype(jnp.float32), x.astype(jnp.float32),
-            axes=(axes, list(range(x.ndim))),
-        )
+        return jnp.tensordot(_acc(s), _acc(x),
+                             axes=(axes, list(range(x.ndim))))
 
     leaves = jax.tree.leaves(jax.tree.map(leaf, stack, v))
     return _sum_leaves(leaves)
@@ -80,9 +83,7 @@ def tree_gram(stack_a: Pytree, stack_b: Pytree) -> jax.Array:
     Axis-preserving contraction — see tree_vdot_stacked sharding note."""
     def leaf(a, b):
         axes = list(range(1, a.ndim))
-        return jnp.tensordot(
-            a.astype(jnp.float32), b.astype(jnp.float32), axes=(axes, axes)
-        )
+        return jnp.tensordot(_acc(a), _acc(b), axes=(axes, axes))
 
     leaves = jax.tree.leaves(jax.tree.map(leaf, stack_a, stack_b))
     return _sum_leaves(leaves)
@@ -91,8 +92,8 @@ def tree_gram(stack_a: Pytree, stack_b: Pytree) -> jax.Array:
 def tree_combine_stacked(stack: Pytree, coeff: jax.Array) -> Pytree:
     """Σ_i coeff[i] * stack[i]  — contraction of the history axis."""
     def leaf(s):
-        s32 = s.astype(jnp.float32)
-        return jnp.tensordot(coeff.astype(jnp.float32), s32, axes=1).astype(s.dtype)
+        sa = _acc(s)
+        return jnp.tensordot(coeff.astype(sa.dtype), sa, axes=1).astype(s.dtype)
 
     return jax.tree.map(leaf, stack)
 

@@ -103,8 +103,8 @@ class TestAndersonKernel:
     @pytest.mark.parametrize("m", [9, 10, 16, 21])
     def test_flat_passes_m_beyond_one_granule(self, m):
         """m > 8 histories (L>8 local epochs, carried cross-round columns):
-        the wrappers pad m to the next 8-sublane granule and the padded
-        columns must contribute nothing."""
+        blocks span all m rows whatever m is, and the padded d lanes must
+        contribute nothing."""
         from repro.kernels.anderson.ops import flat_gram, flat_update
         from repro.kernels.anderson.ref import gram_ref, update_ref
         rng = np.random.default_rng(m)
@@ -168,15 +168,30 @@ class TestAndersonRoundParity:
     CPU): the fused kernels wired into the FULL round core — vmapped clients,
     comm channel, metrics — must reproduce the tree path. Both paths share
     the _solve_gram eigh solve; the only difference is the accumulation
-    order of the one-pass tiled Gram/update, so parity is tight."""
+    order of the one-pass tiled Gram/update, so parity is tight.
 
-    @pytest.fixture(scope="class")
-    def prob(self):
+    Pinned in f64: these AA Grams reach condition numbers of 1e7-1e10, and
+    the solve amplifies the paths' f32 reordering noise (1e-7) to 1e-4 in
+    the state — past rtol 1e-5 even at a 1e-3 Tikhonov.  In f64 both paths
+    accumulate the Gram in f64, where the same amplification stays far
+    inside the bound, and a path that rounds through f32 or bf16 fails it."""
+
+    @pytest.fixture(autouse=True)
+    def x64(self):
+        was = jax.config.read("jax_enable_x64")
+        jax.config.update("jax_enable_x64", True)
+        try:
+            yield
+        finally:
+            jax.config.update("jax_enable_x64", was)
+
+    @pytest.fixture
+    def prob(self, x64):
         from repro.data import make_binary_classification, partition
         from repro.models.logreg import make_logreg_problem
         X, y = make_binary_classification("synthetic_small", n=200, seed=0)
         clients = partition(X, y, num_clients=4, scheme="iid")
-        return make_logreg_problem(clients, gamma=1e-3)
+        return make_logreg_problem(clients, gamma=1e-3, dtype=jnp.float64)
 
     def _roundwise(self, prob, algo, hp, rounds=3, channel=None):
         import dataclasses
@@ -208,7 +223,8 @@ class TestAndersonRoundParity:
     @pytest.mark.parametrize("algo", ["fedosaa_svrg", "fedosaa_scaffold"])
     def test_round_parity_l_gt_8(self, prob, algo):
         """L > 8 local epochs: the per-client history exceeds one 8-sublane
-        granule, exercising the padded-m kernel path inside the round."""
+        granule, exercising a full-m block that is not 8-aligned inside the
+        round."""
         self._roundwise(prob, algo,
                         AlgoHParams(eta=0.5, local_epochs=10), rounds=2)
 

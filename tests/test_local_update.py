@@ -1,5 +1,5 @@
 """Fused dual-gradient local-trajectory kernels (kernels/local_update):
-kernel↔oracle parity (bit-exact where shapes are granule-aligned), the
+kernel↔oracle parity (to f32 reordering noise on granule-aligned shapes), the
 padded-row invariance property, fused↔autodiff round parity, and the
 stack_client_arrays aggregation-weight regression."""
 import dataclasses
@@ -68,32 +68,55 @@ def _rand_case(rng, n, d, link, S=1):
 # kernel (interpret mode) vs the jnp oracle
 # ---------------------------------------------------------------------------
 
+#: kernel↔oracle parity on a single granule-aligned row tile.  The kernel
+#: writes each step's row into a [steps, d] block held across the grid (the
+#: layout Mosaic accepts on a TPU), and the interpreter then rounds r_traj
+#: one f32 ulp away from the oracle on some entries — measured ≤ 3e-8
+#: absolute on residuals of scale 0.35.  So parity is pinned at f32
+#: reordering noise: rtol 1e-6 / atol 1e-7.  A kernel that rounds the design
+#: to bf16 is ≥ 1e-5 off on every case below, two orders past the bound, and
+#: each case asserts that it fails.
+_ULP_TOL = dict(rtol=1e-6, atol=1e-7)
+
+
+def _within_ulps(got, want) -> bool:
+    return all(bool(np.allclose(np.asarray(g), np.asarray(w), **_ULP_TOL))
+               for g, w in zip(got, want))
+
+
+def _assert_kernel_parity(x, y, mask, w0, u, **kw):
+    ref = fused_trajectory(x, y, mask, w0, u, impl="ref", **kw)
+    got = fused_trajectory(x, y, mask, w0, u, impl="kernel", interpret=True,
+                           **kw)
+    for g, r in zip(got, ref):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), **_ULP_TOL)
+    # the bound is tight enough to catch a bf16-degraded design
+    xb = x.astype(jnp.bfloat16).astype(x.dtype)
+    degraded = fused_trajectory(xb, y, mask, w0, u, impl="kernel",
+                                interpret=True, **kw)
+    assert not _within_ulps(degraded, ref), "tolerance too loose for bf16"
+
+
 class TestFusedKernelParity:
     @pytest.mark.parametrize("link", ["logistic", "linear"])
     @pytest.mark.parametrize("anchor", [0.0, 1.0])
     def test_bit_exact_on_granule_shapes(self, link, anchor):
-        """One granule-aligned row tile: the kernel IS the oracle, bitwise
-        (same contractions, same cast points — ref.py docstring)."""
+        """One granule-aligned row tile: the kernel is the oracle to f32
+        reordering noise (same contractions, same cast points — ref.py
+        docstring; see _ULP_TOL for why not bitwise)."""
         rng = np.random.default_rng(hash((link, anchor)) % 2**31)
         x, y, mask, w0, u = _rand_case(rng, 384, 128, link)
         mask = mask.at[0, 350:].set(0.0)
-        kw = dict(link=link, reg=1e-3, eta=0.5, anchor_scale=anchor, steps=11)
-        wr, rr = fused_trajectory(x, y, mask, w0, u, impl="ref", **kw)
-        wk, rk = fused_trajectory(x, y, mask, w0, u, impl="kernel",
-                                  interpret=True, **kw)
-        assert bool(jnp.all(wr == wk)), "w_traj not bit-exact vs ref"
-        assert bool(jnp.all(rr == rk)), "r_traj not bit-exact vs ref"
+        _assert_kernel_parity(x, y, mask, w0, u, link=link, reg=1e-3,
+                              eta=0.5, anchor_scale=anchor, steps=11)
 
     def test_bit_exact_minibatch_blocks(self):
-        """S == steps per-step design blocks, granule-aligned: bit-exact."""
+        """S == steps per-step design blocks, granule-aligned: parity to
+        f32 reordering noise (_ULP_TOL)."""
         rng = np.random.default_rng(3)
         x, y, mask, w0, u = _rand_case(rng, 128, 128, "logistic", S=5)
-        kw = dict(link="logistic", reg=1e-3, eta=0.5, anchor_scale=1.0,
-                  steps=5)
-        wr, rr = fused_trajectory(x, y, mask, w0, u, impl="ref", **kw)
-        wk, rk = fused_trajectory(x, y, mask, w0, u, impl="kernel",
-                                  interpret=True, **kw)
-        assert bool(jnp.all(wr == wk) & jnp.all(rr == rk))
+        _assert_kernel_parity(x, y, mask, w0, u, link="logistic", reg=1e-3,
+                              eta=0.5, anchor_scale=1.0, steps=5)
 
     @pytest.mark.parametrize("n,d,row_tile", [
         (300, 54, None),      # ragged → padded, auto tile

@@ -164,3 +164,37 @@ class TestLMBridge:
                           AlgoHParams(eta=0.3, local_epochs=3), 3)
         assert h.loss[-1] < h.loss[0]
         assert np.isfinite(h.loss).all()
+
+    @pytest.mark.parametrize("aa_impl", ["tree", "pallas"])
+    def test_bf16_params_keep_their_dtype(self, aa_impl):
+        """Published widths train in bf16: the local step's f32 terms (the
+        aggregated global gradient) must not promote the iterate, or the
+        trajectory scan and the engine's round scan refuse the carry."""
+        import dataclasses
+
+        from repro.configs import get_arch
+        from repro.core import (AlgoHParams, init_state, make_round_fn,
+                                run_federated)
+        from repro.core.lm import make_lm_clients, make_lm_problem
+        from repro.models.decoder import build_model
+
+        # one small layer: the interpret-mode AA kernels sweep every param
+        cfg = dataclasses.replace(
+            get_arch("smollm-135m").reduced(), dtype="bfloat16",
+            num_layers=1, d_model=64, num_heads=2, num_kv_heads=1,
+            head_dim=32, d_ff=128, vocab_size=256)
+        model = build_model(cfg)
+        toks = make_lm_tokens(4, 32, cfg.vocab_size)
+        problem = make_lm_problem(model, make_lm_clients(toks, 2))
+        hp = AlgoHParams(eta=0.3, local_epochs=2, aa_impl=aa_impl)
+        state = init_state(problem, jax.random.PRNGKey(0), hp, None,
+                           "fedosaa_svrg")
+        assert {x.dtype for x in jax.tree.leaves(state.params)} == {
+            jnp.dtype(jnp.bfloat16)}
+        new, m = jax.jit(make_round_fn("fedosaa_svrg", problem, hp))(state)
+        for a, b in zip(jax.tree.leaves(new.params),
+                        jax.tree.leaves(state.params)):
+            assert a.dtype == b.dtype and a.shape == b.shape
+        assert np.isfinite(float(m.loss))
+        h = run_federated(problem, "fedosaa_svrg", hp, 2, chunk=2)
+        assert np.isfinite(h.loss).all()
