@@ -23,7 +23,8 @@ Checks the versioned row contract the sink promises:
     checkpoint_failures) is present and sane: all three numeric and
     non-negative (zeros when checkpointing was off), failures an integer,
     and every checkpoint_failed alarm in the footer is reflected by a
-    non-zero failure count.
+    non-zero failure count;
+  * the v5 footer compile count is a non-negative integer.
 
 Exit 0 and a one-line summary on success; exit 1 with the first violation
 otherwise.
@@ -130,6 +131,11 @@ def check_file(path: str) -> dict:
     if footer["checkpoint_failures"] != int(footer["checkpoint_failures"]):
         fail(len(lines), "footer checkpoint_failures="
              f"{footer['checkpoint_failures']} is not an integer count")
+    compiles = footer.get("compiles")
+    if not isinstance(compiles, int) or isinstance(compiles, bool) \
+            or compiles < 0:
+        fail(len(lines), f"footer compiles={compiles!r}, expected a "
+             "non-negative integer count")
     n_failed_alarms = sum(
         1 for a in footer.get("alarms", [])
         if a.get("rule") == "checkpoint_failed")

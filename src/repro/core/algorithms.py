@@ -264,6 +264,8 @@ class RoundMetrics(NamedTuple):
                              # staleness_runaway alarm
 
 
+# a host span: a run's first state is a dozen small eager dispatches
+@partial(jax.profiler.annotate_function, name="fl.init_state")
 def init_state(problem: FLProblem, rng: jax.Array,
                hp: "AlgoHParams | None" = None,
                channel: "CommChannel | str | None" = None,
@@ -953,7 +955,9 @@ def _stack_losses(problem: FLProblem, w: Pytree, x, y, mask) -> jax.Array:
     )
 
 
+@jax.named_scope("fl.anchor_grad")
 def _stack_grads(problem: FLProblem, w: Pytree, x, y, mask) -> Pytree:
+    """Every client's full-batch gradient at the round's anchor w^t."""
     return jax.vmap(lambda xx, yy, mm: problem.grad(w, ClientBatch(xx, yy, mm)))(
         x, y, mask
     )
@@ -967,6 +971,7 @@ def _nan_stats(k: int) -> AAStats:
     )
 
 
+@jax.named_scope("fl.round_metrics")
 def _metric_parts(problem, R, w, g, stats, x, y, mask, dweight,
                   pweight) -> MetricParts:
     """f(w), ‖g‖ and AA/cohort health stats, reduced across every client."""

@@ -65,9 +65,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.compiles import CompileCounter
 from repro.utils import tree_math as tm
 
 Pytree = Any
+
+_span = jax.profiler.TraceAnnotation
 
 
 #: RoundMetrics fields mirrored into RoundTrace columns, in order — the
@@ -104,6 +107,9 @@ class RoundTrace:
                                # over its executed rounds)
     wall_time: np.ndarray      # [T] cumulative seconds
     stopped: bool              # a stop criterion fired (vs round budget spent)
+    computed_rounds: int       # scan slots dispatched: chunk × chunks run,
+                               # with the slots past a stop or past the budget
+    chunk_compiles: np.ndarray # [chunks] backend compiles during each chunk
 
     @property
     def num_rounds(self) -> int:
@@ -160,22 +166,24 @@ def make_chunk_runner(
             # unconditional round + select (NOT lax.cond) — see module
             # docstring: this keeps the chunk bit-exact with the loop
             new_s, m = round_fn(s)
-            if w_star is not None:
-                rel = tm.tree_norm(tm.tree_sub(new_s.params, w_star)) / w_star_norm
-            else:
-                rel = jnp.full((), jnp.nan, jnp.float32)
-            live = jnp.logical_and(~done, i < n_live)
-            new_s = tm.tree_where(live, new_s, s)
-            if tap is not None:
-                jax.debug.callback(tap, i, m, rel, live, ordered=False)
-            # mirror the loop's break order: the row is emitted, THEN the
-            # stop fires — so the stopping round's row is kept
-            stop = ~jnp.isfinite(m.loss)
-            if stop_rel_error is not None:
-                stop = jnp.logical_or(stop, rel < stop_rel_error)
-            if stop_grad_norm is not None:
-                stop = jnp.logical_or(stop, m.grad_norm < stop_grad_norm)
-            done = jnp.logical_or(done, jnp.logical_and(live, stop))
+            with jax.named_scope("fl.stop_check"):
+                if w_star is not None:
+                    rel = (tm.tree_norm(tm.tree_sub(new_s.params, w_star))
+                           / w_star_norm)
+                else:
+                    rel = jnp.full((), jnp.nan, jnp.float32)
+                live = jnp.logical_and(~done, i < n_live)
+                new_s = tm.tree_where(live, new_s, s)
+                if tap is not None:
+                    jax.debug.callback(tap, i, m, rel, live, ordered=False)
+                # mirror the loop's break order: the row is emitted, THEN the
+                # stop fires — so the stopping round's row is kept
+                stop = ~jnp.isfinite(m.loss)
+                if stop_rel_error is not None:
+                    stop = jnp.logical_or(stop, rel < stop_rel_error)
+                if stop_grad_norm is not None:
+                    stop = jnp.logical_or(stop, m.grad_norm < stop_grad_norm)
+                done = jnp.logical_or(done, jnp.logical_and(live, stop))
             return (new_s, done), (m, rel, live)
 
         (state, done), (ms, rels, lives) = jax.lax.scan(
@@ -207,7 +215,13 @@ def run_rounds(
     """Run up to ``num_rounds`` rounds in chunks of ``chunk``; one host sync
     per chunk. Returns ``(final_state, RoundTrace)`` — the state stays
     device-resident, the trace is host numpy with one row per executed round
-    (identical to the per-round Python loop's rows).
+    (identical to the per-round Python loop's rows), plus the scan slots
+    dispatched and the backend compiles of each chunk.
+
+    Each chunk runs under the host span ``fl.chunk`` (a profiler step span,
+    ``step_num`` = global chunk index) with children ``fl.engine.dispatch``,
+    ``fl.engine.wait``, ``fl.engine.fetch`` and ``fl.engine.rows``, so a
+    profiler trace tells the device's idle gaps apart (obs/profiling.py).
 
     ``runner`` — optionally a prebuilt ``make_chunk_runner(...)`` whose
     compiled executable should be reused (e.g. pre-compiled via
@@ -261,51 +275,71 @@ def run_rounds(
     rel_col: list[float] = []
     rw_col: list[float] = []
     wall_col: list[float] = []
+    chunk_compiles: list[int] = []
     t_total = 0.0
     comm_total = 0.0
     executed = 0
     stopped = False
+    counter = CompileCounter()
     try:
         while executed < num_rounds and not stopped:
             n_live = min(chunk, num_rounds - executed)
+            # the profiler window opens and closes outside the chunk's span,
+            # so a traced window holds whole fl.chunk spans
             if trace_capture is not None:
                 trace_capture.on_chunk_start(start_round + executed, n_live)
-            t0 = time.perf_counter()
-            state, done, ms, rels, lives = runner(state, np.int32(n_live))
-            # the ONE host sync of this chunk (device_get blocks on results)
-            done, ms, rels, lives = jax.device_get((done, ms, rels, lives))
-            elapsed = time.perf_counter() - t0
-            idx = np.flatnonzero(lives)
-            per_round = elapsed / max(len(idx), 1)
-            stacked = {f: np.asarray(getattr(ms, f)) for f in METRIC_FIELDS}
-            rows = []
-            for i in idx:
-                t_total += per_round
-                mrow = {f: float(stacked[f][i]) for f in METRIC_FIELDS}
-                comm_total += mrow["comm_bytes"]
-                for f in METRIC_FIELDS:
-                    cols[f].append(mrow[f])
-                rel_col.append(float(rels[i]))
-                rw_col.append(per_round)
-                wall_col.append(t_total)
-                if sinks:
-                    rows.append(build_round_row(
-                        start_round + executed + len(rows), mrow,
-                        float(rels[i]), comm_total, per_round, t_total))
-            executed += len(idx)
-            stopped = bool(done)
-            for s in sinks:
-                s.emit(rows)
-            if any(getattr(s, "stop_requested", False) for s in sinks):
-                stopped = True
+            compiles0 = counter.compiles
+            with jax.profiler.StepTraceAnnotation(
+                    "fl.chunk",
+                    step_num=start_round // chunk + len(chunk_compiles)):
+                t0 = time.perf_counter()
+                with _span("fl.engine.dispatch"):
+                    state, *out = runner(state, np.int32(n_live))
+                # waiting apart from the fetch adds no sync: device_get would
+                # block on the same results
+                with _span("fl.engine.wait"):
+                    jax.block_until_ready(out)
+                # the ONE host sync of this chunk
+                with _span("fl.engine.fetch"):
+                    done, ms, rels, lives = jax.device_get(out)
+                elapsed = time.perf_counter() - t0
+                with _span("fl.engine.rows"):
+                    idx = np.flatnonzero(lives)
+                    per_round = elapsed / max(len(idx), 1)
+                    stacked = {f: np.asarray(getattr(ms, f))
+                               for f in METRIC_FIELDS}
+                    rows = []
+                    for i in idx:
+                        t_total += per_round
+                        mrow = {f: float(stacked[f][i]) for f in METRIC_FIELDS}
+                        comm_total += mrow["comm_bytes"]
+                        for f in METRIC_FIELDS:
+                            cols[f].append(mrow[f])
+                        rel_col.append(float(rels[i]))
+                        rw_col.append(per_round)
+                        wall_col.append(t_total)
+                        if sinks:
+                            rows.append(build_round_row(
+                                start_round + executed + len(rows), mrow,
+                                float(rels[i]), comm_total, per_round,
+                                t_total))
+                    executed += len(idx)
+                    stopped = bool(done)
+                    for s in sinks:
+                        s.emit(rows)
+                    if any(getattr(s, "stop_requested", False) for s in sinks):
+                        stopped = True
+                    if checkpoint is not None:
+                        # state buffers are about to be donated to the NEXT
+                        # chunk: maybe_save snapshots host copies before
+                        # dispatching the (async) write
+                        checkpoint.maybe_save(state, start_round + executed,
+                                              elapsed)
+            chunk_compiles.append(counter.compiles - compiles0)
             if trace_capture is not None:
                 trace_capture.on_chunk_end(start_round + executed)
-            if checkpoint is not None:
-                # state buffers are about to be donated to the NEXT chunk:
-                # maybe_save snapshots host copies before dispatching the
-                # (async) write
-                checkpoint.maybe_save(state, start_round + executed, elapsed)
     finally:
+        counter.close()
         if trace_capture is not None:
             trace_capture.close()
         if checkpoint is not None:
@@ -316,7 +350,7 @@ def run_rounds(
         footer = build_footer(
             executed, stopped, alarms,
             checkpoint=checkpoint.telemetry() if checkpoint is not None
-            else None)
+            else None, compiles=counter.compiles)
         for s in sinks:
             s.close(footer)
     trace = RoundTrace(
@@ -325,5 +359,7 @@ def run_rounds(
         round_wall=np.asarray(rw_col),
         wall_time=np.asarray(wall_col),
         stopped=stopped,
+        computed_rounds=chunk * len(chunk_compiles),
+        chunk_compiles=np.asarray(chunk_compiles, np.int64),
     )
     return state, trace

@@ -321,6 +321,7 @@ def run_federated(
         # eagerly dispatched O(n_leaves) kernels per round
         rel_fn = jax.jit(lambda p: tm.tree_norm(tm.tree_sub(p, w_star)))
 
+    from repro.obs.compiles import CompileCounter
     from repro.obs.sinks import (
         ROW_FIELDS, SCHEMA_VERSION, build_footer, build_round_row,
     )
@@ -335,6 +336,7 @@ def run_federated(
     comm_total = 0.0
     t_total = 0.0
     stopped = False
+    counter = CompileCounter()
     try:
         for t in range(start_round, num_rounds):
             if trace_capture is not None:
@@ -377,6 +379,7 @@ def run_federated(
                 stopped = True
                 break
     finally:
+        counter.close()
         if trace_capture is not None:
             trace_capture.close()
         if ckpt_mgr is not None:
@@ -387,7 +390,7 @@ def run_federated(
         footer = build_footer(
             len(rows), stopped, alarms,
             checkpoint=ckpt_mgr.telemetry() if ckpt_mgr is not None
-            else None)
+            else None, compiles=counter.compiles)
         for s in sinks:
             s.close(footer)
 

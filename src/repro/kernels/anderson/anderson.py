@@ -86,6 +86,7 @@ def gram_pallas(y: jax.Array, g: jax.Array, tile: int = DEFAULT_TILE,
             jax.ShapeDtypeStruct((1, m), acc),
         ],
         interpret=interpret,
+        name="aa_gram_kernel",
     )(y, g.reshape(1, d))
     return gram, yg[0]
 
@@ -139,6 +140,7 @@ def update_pallas(w, g, s, y, gamma, eta, beta, tile: int = DEFAULT_TILE,
         out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), w.dtype),
         interpret=interpret,
+        name="aa_update_kernel",
     )(
         w.reshape(1, d), g.reshape(1, d), s, y,
         gamma.reshape(1, m).astype(acc),

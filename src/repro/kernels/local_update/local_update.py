@@ -194,5 +194,6 @@ def trajectory_pallas(x, y, mask, w0, u, invn, *, link: str, eta: float,
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name="fl_local_trajectory_kernel",
     )(x, y, mask, w0, u, invn)
     return w_traj, r_traj

@@ -1,7 +1,7 @@
 """Round telemetry for the device-resident engine (ROADMAP: engine
 observability).
 
-Three layers, all fed from the ONE host sync per engine chunk — attaching
+Four layers, all fed from the ONE host sync per engine chunk — attaching
 telemetry never adds a device→host transfer to the hot loop (pinned in
 tests/test_obs.py):
 
@@ -16,9 +16,14 @@ tests/test_obs.py):
                     bit-exactly — see sinks.LiveTap).
   * ``profiling`` — on-demand ``jax.profiler.trace`` windows around chunk
                     execution ("trace rounds T..T+N", armed by flag or a
-                    trigger file), attributing time to the ``jax.named_scope``
-                    round phases annotated in core/algorithms.py /
-                    core/sharded.py.
+                    trigger file); device time is attributed to the
+                    ``jax.named_scope`` round phases and the named Pallas
+                    kernels, the device's idle gaps to the engine's host
+                    spans (``fl.chunk``, ``fl.engine.*``, ``fl.init_state``;
+                    the full list is in profiling.py).
+  * ``compiles``  — backend compile counts from ``jax.monitoring``: the
+                    engine records them per chunk (``RoundTrace``) and the
+                    footer carries the run's total.
   * ``alarms``    — declarative health rules over the streamed rows
                     (non-finite loss, AA Gram conditioning, column-filtering
                     collapse, rel-error plateau) that log structured warnings
@@ -29,11 +34,10 @@ from repro.obs.alarms import (  # noqa: F401
     AlarmMonitor,
     AlarmRule,
 )
+from repro.obs.compiles import CompileCounter  # noqa: F401
 from repro.obs.profiling import (  # noqa: F401
     TraceCapture,
     TraceConfig,
-    find_trace_files,
-    trace_contains,
 )
 from repro.obs.sinks import (  # noqa: F401
     ROW_FIELDS,

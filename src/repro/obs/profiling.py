@@ -5,10 +5,29 @@
 whole chunks inside one jit, the window is aligned OUTWARD to chunk
 boundaries: asking for rounds [T, T+N) starts the trace before the first
 chunk that overlaps the window and stops it after the first chunk boundary
-at or past T+N. Time inside the trace is attributed to round phases by the
-``jax.named_scope`` annotations in core/algorithms.py / core/sharded.py /
-core/anderson.py ("fl.cohort_plan", "fl.cohort_gather",
-"fl.local_trajectory", "fl.aa_step", "fl.uplink", "fl.psum", "fl.scatter").
+at or past T+N.
+
+What a window holds, all on the profiler's one clock:
+
+  * device time by round phase — the ``jax.named_scope`` annotations that
+    reach each compiled instruction's ``op_name``: ``fl.cohort_plan``,
+    ``fl.cohort_gather``, ``fl.anchor_grad`` (the anchor gradient each round
+    sends up), ``fl.local_trajectory``, ``fl.aa_step``, ``fl.uplink``,
+    ``fl.psum`` (sharded), ``fl.scatter``, ``fl.round_metrics`` (loss, norms
+    and AA health reduced for the row) and ``fl.stop_check`` (the engine's
+    rel-error, live/stop select and stop test) in core/algorithms.py,
+    core/anderson.py, core/sharded.py and core/engine.py;
+  * the Pallas kernels by name (``pallas_call(name=...)``):
+    ``fl_local_trajectory_kernel`` (kernels/local_update), ``aa_gram_kernel``
+    and ``aa_update_kernel`` (kernels/anderson);
+  * host spans (``jax.profiler.TraceAnnotation``), which tell the device's
+    idle gaps apart: ``fl.init_state`` around a run's first state, and per
+    chunk a ``fl.chunk`` step span (``step_num`` = global chunk index) with
+    children ``fl.engine.dispatch`` (enqueue the compiled chunk),
+    ``fl.engine.wait`` (until its results are ready), ``fl.engine.fetch``
+    (the one device→host transfer) and ``fl.engine.rows`` (row building,
+    sinks, alarms, checkpoint hook). A window opens before a chunk's span
+    and closes after it, so it holds whole chunks.
 
 Two arming modes:
 
@@ -18,15 +37,11 @@ Two arming modes:
     flight and the next chunk gets traced (the file is consumed/unlinked so
     each touch yields one window).
 
-On this jax version the profiler writes
-``<dir>/plugins/profile/<ts>/<host>.xplane.pb`` (plus a perfetto
-``.trace.json.gz``); named-scope strings land in the xplane proto only, so
-``trace_contains`` greps the ``.pb`` bytes — that is also what the trace
-acceptance test pins.
+The profiler writes ``<dir>/plugins/profile/<ts>/<host>.xplane.pb``;
+``jax.profiler.ProfileData.from_file`` reads it (planes, lines, events).
 """
 from __future__ import annotations
 
-import glob
 import logging
 import os
 from dataclasses import dataclass
@@ -119,23 +134,4 @@ class TraceCapture:
             self.windows.append((self._started_at, -1))
 
 
-def find_trace_files(trace_dir: str, suffix: str = ".xplane.pb") -> list:
-    """Profiler output files under ``trace_dir`` (any capture session)."""
-    pattern = os.path.join(trace_dir, "plugins", "profile", "*", f"*{suffix}")
-    return sorted(glob.glob(pattern))
-
-
-def trace_contains(trace_dir: str, name: str) -> bool:
-    """True if any captured xplane proto mentions ``name`` (e.g. a
-    ``jax.named_scope`` label). String-level grep of the .pb bytes — scope
-    names are stored verbatim in the xplane string table, so this needs no
-    proto parser."""
-    needle = name.encode()
-    for path in find_trace_files(trace_dir):
-        with open(path, "rb") as f:
-            if needle in f.read():
-                return True
-    return False
-
-
-__all__ = ["TraceCapture", "TraceConfig", "find_trace_files", "trace_contains"]
+__all__ = ["TraceCapture", "TraceConfig"]

@@ -11,14 +11,14 @@ v2 added aa_clipped_max, the robustness layer's clip-screen activity; v3
 added arrivals/staleness_mean/staleness_max, the deadline gate's per-round
 activity — null whenever AsyncConfig is off; v4 added the checkpoint
 telemetry triple to the footer — always present, zeros when checkpointing
-is off):
+is off; v5 added the run's backend compile count to the footer):
 
-  header row  {"v": 4, "kind": "header", "fields": [...], ...run metadata:
+  header row  {"v": 5, "kind": "header", "fields": [...], ...run metadata:
                algo / runtime / channel / num_clients / cohort_size / chunk /
                num_rounds / uplink_bytes (per-UplinkSpec byte breakdown from
                the comm schema) / backend}
-  round row   {"v": 4, "kind": "round", "round": t, <ROW_FIELDS>}
-  footer row  {"v": 4, "kind": "footer", "rounds": T, "stopped": bool,
+  round row   {"v": 5, "kind": "round", "round": t, <ROW_FIELDS>}
+  footer row  {"v": 5, "kind": "footer", "rounds": T, "stopped": bool,
                "alarms": [...],
                "checkpoint_save_ms": cumulative wall spent in saves
                (snapshot + serialize + commit, async or not),
@@ -26,7 +26,9 @@ is off):
                "checkpoint_failures": saves that exhausted their I/O
                retries (the run continued; each also appears in "alarms"
                as a checkpoint_failed event, and a save overrunning its
-               chunk's compute appears as checkpoint_stalled)}
+               chunk's compute appears as checkpoint_stalled),
+               "compiles": backend compiles during the run (persistent-cache
+               loads included; RoundTrace.chunk_compiles says which chunk)}
 
 Round-row fields (ROW_FIELDS):
 
@@ -68,7 +70,7 @@ from typing import Any, Protocol, runtime_checkable
 
 import numpy as np
 
-SCHEMA_VERSION = 4
+SCHEMA_VERSION = 5
 
 #: canonical per-round row fields, in emission order (after "round")
 ROW_FIELDS = (
@@ -124,7 +126,7 @@ def build_round_row(round_idx: int, metrics: "dict[str, float]", rel: float,
 
 
 def build_footer(rounds: int, stopped: bool, alarms: "list[dict]",
-                 checkpoint: dict | None = None) -> dict:
+                 checkpoint: dict | None = None, compiles: int = 0) -> dict:
     """The versioned run footer. ``checkpoint`` is a CheckpointManager's
     ``telemetry()`` dict; the three fields are always emitted (zeros when no
     checkpointing ran) so v4 consumers never branch on presence."""
@@ -138,6 +140,7 @@ def build_footer(rounds: int, stopped: bool, alarms: "list[dict]",
         "checkpoint_save_ms": float(ckpt.get("checkpoint_save_ms", 0.0)),
         "checkpoint_bytes": int(ckpt.get("checkpoint_bytes", 0)),
         "checkpoint_failures": int(ckpt.get("checkpoint_failures", 0)),
+        "compiles": int(compiles),
     }
 
 

@@ -270,3 +270,36 @@ class TestEngineMechanics:
                            "fedosaa_svrg")
         txt = runner.lower(state, np.int32(4)).compile().as_text()
         assert "while" in txt  # the rounds live in one compiled scan loop
+
+    def test_computed_rounds_and_compiles_per_chunk(self, setup):
+        """RoundTrace counts the scan slots dispatched (chunk × chunks, the
+        slots past the stop included) and the backend compiles each chunk
+        caused: the first chunk compiles the runner, later chunks and a
+        second run on the same runner compile nothing; the footer carries
+        the run's total."""
+        from repro.obs import MemorySink
+
+        prob, wstar, _ = setup
+        hp = AlgoHParams(eta=0.5, local_epochs=3)
+        rf = make_round_fn("fedosaa_svrg", prob, hp)
+        runner = make_chunk_runner(rf, 5, w_star=wstar, stop_rel_error=1e-3)
+
+        def run():
+            sink = MemorySink()
+            state = init_state(prob, jax.random.PRNGKey(0), hp, None,
+                               "fedosaa_svrg")
+            _, trace = run_rounds(rf, state, 40, chunk=5, runner=runner,
+                                  sinks=[sink])
+            return trace, sink.footer
+
+        first, footer = run()
+        assert first.stopped and first.num_rounds % 5 != 0
+        chunks = -(-first.num_rounds // 5)
+        assert len(first.chunk_compiles) == chunks
+        assert first.computed_rounds == 5 * chunks > first.num_rounds
+        assert first.chunk_compiles[0] >= 1
+        assert not first.chunk_compiles[1:].any()
+        assert footer["compiles"] == first.chunk_compiles.sum()
+        again, footer = run()
+        assert again.computed_rounds == first.computed_rounds
+        assert not again.chunk_compiles.any() and footer["compiles"] == 0

@@ -11,13 +11,16 @@ The load-bearing contracts pinned here:
   * LIVE TAP — the opt-in ``jax.debug.callback`` tap observes the compiled
     math's own values: chunk results stay bit-exact with the tapless runner,
     and non-live slots are dropped.
-  * TRACE CAPTURE — a static window produces a loadable xplane.pb whose
-    string table contains the ``jax.named_scope`` phase annotations
-    (fl.cohort_plan / cohort_gather / local_trajectory / aa_step / uplink /
-    scatter; fl.psum is sharded-only and checked in the compiled HLO).
+  * TRACE CAPTURE — the round-phase ``jax.named_scope`` annotations reach
+    the compiled runner's op metadata (fl.cohort_plan / cohort_gather /
+    anchor_grad / local_trajectory / aa_step / uplink / scatter /
+    round_metrics / stop_check; fl.psum is sharded-only), and a static
+    window produces a loadable xplane.pb holding the engine's host spans
+    (fl.chunk and its fl.engine.* children).
   * ROW SCHEMA — the JSONL emission passes scripts/check_metrics_jsonl.py,
     and the engine emits one row per EXECUTED round (header/footer framed).
 """
+import glob
 import json
 import os
 import subprocess
@@ -52,9 +55,7 @@ from repro.obs import (
     StdoutSink,
     TraceCapture,
     TraceConfig,
-    find_trace_files,
     make_sink,
-    trace_contains,
 )
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -273,25 +274,52 @@ class TestLiveTap:
 
 class TestTraceCapture:
     def test_static_window_produces_scoped_trace(self, setup, tmp_path):
-        """--trace-rounds acceptance: the window yields a loadable xplane.pb
-        whose string table holds every vmap round-phase scope."""
+        """--trace-rounds acceptance: the runner's compiled HLO carries
+        every vmap round-phase scope in its op metadata, and the window's
+        xplane.pb holds the engine's host spans for the one chunk it
+        covers — each fl.engine.* span inside its fl.chunk step span."""
+        from jax.profiler import ProfileData
+
         prob, _, _ = setup
         hp = AlgoHParams(eta=0.5, local_epochs=3, cohort_size=4)
         rf = make_round_fn("fedosaa_svrg", prob, hp, "int8")
         state = init_state(prob, jax.random.PRNGKey(0), hp, "int8",
                            "fedosaa_svrg")
+        runner = make_chunk_runner(rf, 2)
+        txt = runner.lower(state, np.int32(2)).compile().as_text()
+        for scope in ("fl.cohort_plan", "fl.cohort_gather", "fl.anchor_grad",
+                      "fl.local_trajectory", "fl.aa_step", "fl.uplink",
+                      "fl.scatter", "fl.round_metrics", "fl.stop_check"):
+            assert scope in txt, scope
         tdir = str(tmp_path / "trace")
         tc = TraceCapture(TraceConfig(trace_dir=tdir, start_round=0,
                                       num_rounds=2))
-        _, trace = run_rounds(rf, state, 4, chunk=2, trace_capture=tc)
+        _, trace = run_rounds(rf, state, 4, chunk=2, runner=runner,
+                              trace_capture=tc)
         assert trace.num_rounds == 4
         assert tc.windows == [(0, 2)]
         assert not tc.active
-        assert find_trace_files(tdir)
-        for scope in ("fl.cohort_plan", "fl.cohort_gather",
-                      "fl.local_trajectory", "fl.aa_step", "fl.uplink",
-                      "fl.scatter"):
-            assert trace_contains(tdir, scope), scope
+        [path] = glob.glob(os.path.join(tdir, "plugins", "profile", "*",
+                                        "*.xplane.pb"))
+        spans = {}
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host"):
+                for line in plane.lines:
+                    for ev in line.events:
+                        if ev.name.startswith("fl."):
+                            spans.setdefault(ev.name, []).append(ev)
+        [chunk] = spans.pop("fl.chunk")
+        assert dict(chunk.stats)["step_num"] == 0
+        assert sorted(spans) == ["fl.engine.dispatch", "fl.engine.fetch",
+                                 "fl.engine.rows", "fl.engine.wait"]
+        lo, hi = chunk.start_ns, chunk.start_ns + chunk.duration_ns
+        order = sorted((ev.start_ns, name) for name, evs in spans.items()
+                       for ev in evs)
+        assert [name for _, name in order] == [
+            "fl.engine.dispatch", "fl.engine.wait", "fl.engine.fetch",
+            "fl.engine.rows"]
+        for [ev] in spans.values():
+            assert lo <= ev.start_ns and ev.start_ns + ev.duration_ns <= hi
 
     def test_psum_scope_in_sharded_hlo(self, setup):
         """fl.psum wraps the sharded all-reduce; cheap compiled-HLO check
