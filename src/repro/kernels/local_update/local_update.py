@@ -19,30 +19,44 @@ ONCE per local step — and when the whole design block fits in VMEM (one row
 tile), the Pallas pipeline elides the re-fetch across grid steps entirely:
 the L-step loop runs on-chip with X resident.
 
+Every contraction here is a matrix-vector product (one row of logits, one
+gradient column), which would run the 128-row matrix unit at 1/128 of its
+rows.  So none of them is a dot: the kernel multiplies and reduces on the
+vector unit, in exact f32, over a FEATURE-MAJOR design block.  Features sit
+on sublanes (d padded only to the 8-sublane granule) and rows on lanes, so
+for each 128-row lane group of the tile
+
+    z  = Σ_sublanes(xt · w)       the live logits, one [1, 128] row
+    z0 = Σ_sublanes(xt · w0)      the anchor logits, from the same vregs
+    acc += xt · c                 the backward, [d8, 128] partial sums
+
+and the accumulator is reduced across lanes once, at the last row tile of
+each step, into the gradient column.  The logits land as rows, the layout
+the targets and mask already have.
+
 Layout (one client; the round cores vmap this over K):
 
-    x:    [S·n, d]     design blocks, S stacked on the row axis — S == 1
-                       (full batch: every step revisits block 0, which is
-                       what keeps it resident) or S == steps (per-step
-                       minibatch gathers).  Kept 2-D: the row tile is a
-                       plain (row_tile, d) block
+    xt:   [S, d8, n]   design blocks, feature-major: S == 1 (full batch:
+                       every step revisits block 0, which is what keeps it
+                       resident) or S == steps (per-step minibatch gathers)
     y:    [S, 1, n]    targets (±1 for the logistic link)
     mask: [S, 1, n]    0/1 row validity (padded rows contribute exactly 0)
-    w0:   [1, d]       start == anchor w^t
-    u:    [1, d]       constant additive correction (see above)
+    w0:   [d8, 1]      start == anchor w^t, a column
+    u:    [d8, 1]      constant additive correction (see above)
     invn: [1, 1]       1 / n_eff (the loss's masked-mean denominator)
 
 Grid is (steps, row_tiles) — row tiles iterate fastest; a VMEM scratch pair
-(w_cur, acc) carries the iterate and the gradient accumulator across grid
-steps.  Every block's last two dims are (8, 128)-aligned or span the whole
-array, as Mosaic requires: y/mask carry a unit middle axis so a step's
-(1, row_tile) slice is a full-height block, and the [steps, d] (w_traj,
-r_traj) outputs are ONE block resident across the grid, written one row at
-the last row tile of every step and flushed to HBM once.
+(w_cur [d8, 1], acc [d8, 128]) carries the iterate and the gradient partial
+sums across grid steps.  Every block's last two dims are (8, 128)-aligned or
+span the whole array, as Mosaic requires: y/mask carry a unit middle axis so
+a step's (1, row_tile) slice is a full-height block, and the (w_traj,
+r_traj) outputs are ONE [steps, d8, 1] block each, resident across the grid,
+written one column at the last row tile of every step and flushed to HBM
+once (ops.py turns them into [steps, d] rows).
 
-The [steps, d] trajectories, w_cur and the dual logit/coefficient buffers
-live in VMEM; only X (once per step, at worst) and the emitted trajectory
-rows touch HBM.  ``ref.py`` is the op-identical jnp oracle.
+The trajectories, w_cur and the accumulator live in VMEM; only X (once per
+step, at worst) and the emitted trajectory columns touch HBM.  ``ref.py`` is
+the op-identical jnp oracle.
 """
 from __future__ import annotations
 
@@ -54,19 +68,17 @@ from jax.experimental.pallas import tpu as pltpu
 #: links the kernel family knows how to differentiate
 LINKS = ("logistic", "linear")
 
-#: default row-tile height (lane-granule multiple; see ops.py for sizing)
+#: default row-tile width (lane-granule multiple; see ops.py for sizing)
 DEFAULT_ROW_TILE = 512
 
-#: f32 contractions at full f32 precision. At Mosaic's default an f32 dot
-#: rounds its operands to bf16: the logits, and so the local fixed point,
-#: drift, and FedOSAA-SVRG on the paper covtype split (K=100) bottomed out
-#: at rel-error 9e-3 on a v5e instead of 1.1e-5.
-PRECISION = jax.lax.Precision.HIGHEST
+#: rows per inner step of the kernel: one lane-width vreg column
+LANES = 128
 
-#: scoped-VMEM limit of one kernel call. At PRECISION the resident K=100
-#: covtype block (5,888x128 f32, double-buffered) needs 18.9 MB of scoped
-#: VMEM, past Mosaic's 16 MB default; a v5e core has 128 MiB.
-VMEM_LIMIT = 64 * 1024 * 1024
+#: lane groups per iteration of the kernel's inner loop (Mosaic unrolls a
+#: loop only fully).  On a v5e the covtype K=100 kernel took 2.84 ms a round
+#: at 1, 1.32 ms at 8 and 1.24 ms fully unrolled, whose code grows with the
+#: tile; at K=10 every unroll from 2 reads 1.99 ms, the HBM stream's time.
+UNROLL = 8
 
 
 def link_coeff(link: str, z: jax.Array, y: jax.Array, mask: jax.Array):
@@ -103,38 +115,46 @@ def _make_traj_kernel(link: str, eta: float, reg: float, anchor: bool,
         def _zero():
             acc[...] = jnp.zeros_like(acc)
 
-        x = x_ref[...].astype(compute_dtype)        # [Tn, d]
-        yv = y_ref[...].astype(compute_dtype)       # [1, Tn]
-        mv = m_ref[...].astype(compute_dtype)       # [1, Tn]
-        w = wcur[...]                               # [1, d]
+        d8, tile = x_ref.shape
+        w = jnp.broadcast_to(wcur[...], (d8, LANES))
+        w0 = jnp.broadcast_to(w0_ref[...].astype(compute_dtype), (d8, LANES))
 
-        # forward: live logits from the tile already in VMEM ...
-        z = jax.lax.dot_general(
-            w, x, (((1,), (1,)), ((), ())), precision=PRECISION,
-            preferred_element_type=compute_dtype)   # [1, Tn]
-        c = link_coeff(link, z, yv, mv)
-        if anchor:
-            # ... and the anchor logits from the SAME tile — the second
-            # gradient of the dual-gradient residual costs no extra X fetch
-            z0 = jax.lax.dot_general(
-                w0_ref[...].astype(compute_dtype), x,
-                (((1,), (1,)), ((), ())), precision=PRECISION,
-                preferred_element_type=compute_dtype)
-            c = c - link_coeff(link, z0, yv, mv)
-        # one combined backward accumulation: both residual contributions
-        # ride a single Xᵀ(·) sweep of the tile
-        acc[...] += jax.lax.dot_general(
-            c, x, (((1,), (0,)), ((), ())), precision=PRECISION,
-            preferred_element_type=compute_dtype)   # [1, d]
+        def lane_group(g, part):
+            cols = pl.ds(pl.multiple_of(g * LANES, LANES), LANES)
+            xt = x_ref[:, cols].astype(compute_dtype)        # [d8, 128]
+            yv = y_ref[:, cols].astype(compute_dtype)        # [1, 128]
+            mv = m_ref[:, cols].astype(compute_dtype)        # [1, 128]
+            # forward: live logits from the vregs just loaded ...
+            z = jnp.sum(xt * w, axis=0, keepdims=True)       # [1, 128]
+            c = link_coeff(link, z, yv, mv)
+            if anchor:
+                # ... and the anchor logits from the SAME vregs — the second
+                # gradient of the dual-gradient residual costs no extra load
+                z0 = jnp.sum(xt * w0, axis=0, keepdims=True)
+                c = c - link_coeff(link, z0, yv, mv)
+            # one combined backward accumulation: both residual
+            # contributions ride a single Xᵀ(·) sweep of the lane group
+            return part + xt * c                             # [d8, 128]
+
+        def unrolled(j, part):
+            for k in range(UNROLL):
+                part = lane_group(j * UNROLL + k, part)
+            return part
+
+        groups = tile // LANES
+        part = jax.lax.fori_loop(0, groups // UNROLL, unrolled, acc[...])
+        for g in range(groups - groups % UNROLL, groups):
+            part = lane_group(g, part)
+        acc[...] = part
 
         @pl.when(i == n_tiles - 1)
         def _emit():
-            w_now = wcur[...]
-            r = (acc[...] * invn_ref[0, 0].astype(compute_dtype)
+            w_now = wcur[...]                                # [d8, 1]
+            grad = jnp.sum(acc[...], axis=1, keepdims=True)  # [d8, 1]
+            r = (grad * invn_ref[0, 0].astype(compute_dtype)
                  + reg * w_now + u_ref[...].astype(compute_dtype))
-            row = pl.ds(step, 1)
-            wt_ref[row, :] = w_now.astype(wt_ref.dtype)
-            rt_ref[row, :] = r.astype(rt_ref.dtype)
+            wt_ref[step] = w_now.astype(wt_ref.dtype)
+            rt_ref[step] = r.astype(rt_ref.dtype)
             wcur[...] = w_now - eta * r
 
     return kernel
@@ -144,21 +164,22 @@ def trajectory_pallas(x, y, mask, w0, u, invn, *, link: str, eta: float,
                       reg: float, anchor_scale: float, steps: int,
                       row_tile: int = DEFAULT_ROW_TILE,
                       interpret: bool = False):
-    """x: [S·n, d] (S stacked on rows); y, mask: [S, 1, n]; w0, u: [1, d];
+    """x: [S, d8, n] feature-major; y, mask: [S, 1, n]; w0, u: [d8, 1];
     invn: [1, 1].
 
     S must be 1 (resident full-batch design) or ``steps`` (per-step
-    minibatch blocks); n % row_tile == 0.  Returns (w_traj, r_traj), each
-    [steps, d] in w0.dtype.
+    minibatch blocks); n % row_tile == 0 and row_tile % 128 == 0.  Returns
+    (w_traj, r_traj), each [steps, d8, 1] in w0.dtype.
     """
     S, _, n = y.shape
-    d = x.shape[1]
-    if x.shape[0] != S * n:
-        raise ValueError(f"x rows {x.shape[0]} != S*n = {S}*{n}")
+    d8 = x.shape[1]
+    if x.shape != (S, d8, n):
+        raise ValueError(f"x shape {x.shape} != (S, d8, n) = ({S}, d8, {n})")
     if S not in (1, steps):
         raise ValueError(f"S={S} must be 1 or steps={steps}")
-    if n % row_tile:
-        raise ValueError(f"n={n} not a multiple of row_tile={row_tile}")
+    if n % row_tile or row_tile % LANES:
+        raise ValueError(f"n={n} not a multiple of row_tile={row_tile}, or "
+                         f"row_tile not a multiple of {LANES}")
     if anchor_scale not in (0.0, 1.0):
         raise ValueError(f"anchor_scale must be 0.0 or 1.0, got {anchor_scale}")
     compute_dtype = jnp.float64 if w0.dtype == jnp.float64 else jnp.float32
@@ -166,33 +187,28 @@ def trajectory_pallas(x, y, mask, w0, u, invn, *, link: str, eta: float,
     sidx = (lambda l: l) if S > 1 else (lambda l: 0)
     kernel = _make_traj_kernel(link, float(eta), float(reg),
                                anchor_scale == 1.0, compute_dtype)
+    row_block = pl.BlockSpec((pl.squeezed, 1, row_tile),
+                             lambda l, i: (sidx(l), 0, i))
+    column = pl.BlockSpec((d8, 1), lambda l, i: (0, 0))
+    traj = pl.BlockSpec((steps, d8, 1), lambda l, i: (0, 0, 0))
     w_traj, r_traj = pl.pallas_call(
         kernel,
         grid=(steps, n_tiles),
         in_specs=[
-            pl.BlockSpec((row_tile, d),
-                         lambda l, i: (sidx(l) * n_tiles + i, 0)),
-            pl.BlockSpec((pl.squeezed, 1, row_tile),
+            pl.BlockSpec((pl.squeezed, d8, row_tile),
                          lambda l, i: (sidx(l), 0, i)),
-            pl.BlockSpec((pl.squeezed, 1, row_tile),
-                         lambda l, i: (sidx(l), 0, i)),
-            pl.BlockSpec((1, d), lambda l, i: (0, 0)),
-            pl.BlockSpec((1, d), lambda l, i: (0, 0)),
+            row_block,
+            row_block,
+            column,
+            column,
             pl.BlockSpec((1, 1), lambda l, i: (0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((steps, d), lambda l, i: (0, 0)),
-            pl.BlockSpec((steps, d), lambda l, i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((steps, d), w0.dtype),
-            jax.ShapeDtypeStruct((steps, d), w0.dtype),
-        ],
+        out_specs=[traj, traj],
+        out_shape=[jax.ShapeDtypeStruct((steps, d8, 1), w0.dtype)] * 2,
         scratch_shapes=[
-            pltpu.VMEM((1, d), compute_dtype),   # w_cur
-            pltpu.VMEM((1, d), compute_dtype),   # gradient accumulator
+            pltpu.VMEM((d8, 1), compute_dtype),       # w_cur
+            pltpu.VMEM((d8, LANES), compute_dtype),   # gradient partial sums
         ],
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="fl_local_trajectory_kernel",
     )(x, y, mask, w0, u, invn)

@@ -2,20 +2,26 @@
 
 ``fused_trajectory`` is what the round cores (core/algorithms.py, under
 ``AlgoHParams.local_impl="pallas"``) call per client: it handles the
-lane/sublane granule padding and row-tile sizing, then dispatches to
+feature-major layout, the granule padding and row-tile sizing, then
+dispatches to
 
   * the Pallas kernel (local_update.py) on TPU — native compilation, X
     streamed once per local step (resident across steps when one row tile
-    covers the design block);
+    covers the design block), every contraction a multiply-and-reduce on
+    the vector unit;
   * the op-identical jnp oracle (ref.py) elsewhere — the SAME fused
     algorithm (one forward + one combined backward sweep per step, anchor
     coefficients hoisted for resident designs) without the interpret-mode
     emulation tax, exactly like the quant codec's CPU path.
 
-Padded rows carry mask 0 and padded feature lanes are zero, so neither can
-influence the trajectories (hypothesis-tested); n pads to the 128-lane
-granule (the row axis is the LAST axis of the y/mask blocks) and d to the
-128-lane granule.  Interpret-mode kernel runs are for parity tests.
+The kernel takes its design block feature-major, [S, d8, n]: this module
+pads the rows to the row tile and the features to the sublane granule (8
+for f32, so covtype's d=54 becomes 56) and transposes, in one XLA copy
+under the caller's ``fl.local_trajectory`` scope.  Row tiles are sized in
+those feature-major bytes.  Padded rows carry mask 0 and padded features
+are zero, so neither can influence the trajectories (hypothesis-tested).
+The kernel's [steps, d8, 1] trajectory columns come back here as [steps, d]
+rows.  Interpret-mode kernel runs are for parity tests.
 """
 from __future__ import annotations
 
@@ -23,7 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.local_update.local_update import (
-    DEFAULT_ROW_TILE,
+    LANES,
     LINKS,
     trajectory_pallas,
 )
@@ -36,10 +42,11 @@ FUSED_IMPLS = ("auto", "kernel", "ref")
 #: kernel through full rounds
 DEFAULT_IMPL = "auto"
 
-#: keep one X row tile comfortably inside VMEM (bytes, f32)
+#: keep one feature-major X row tile comfortably inside VMEM (bytes)
 TILE_BUDGET = 2 * 1024 * 1024
-#: designs up to this many bytes use ONE row tile — the Pallas pipeline
-#: then elides the X re-fetch across local steps (fully resident loop)
+#: feature-major designs up to this many bytes use ONE row tile — the
+#: Pallas pipeline then elides the X re-fetch across local steps (fully
+#: resident loop)
 RESIDENT_BUDGET = 4 * 1024 * 1024
 
 _ON_TPU = None
@@ -52,18 +59,19 @@ def _use_kernel_default() -> bool:
     return _ON_TPU
 
 
-def _granule(v: int, g: int = 128) -> int:
+def _granule(v: int, g: int = LANES) -> int:
     return ((v + g - 1) // g) * g
 
 
 def _pick_row_tile(S: int, n_pad: int, d_pad: int, itemsize: int) -> int:
-    """Row-tile height: the whole block when it fits the resident budget
+    """Row-tile width: the whole block when it fits the resident budget
     (S==1 → X stays in VMEM across every local step), else the fewest
     128-granule tiles inside the per-tile budget, balanced so the rows
     padded onto the last tile stay under one granule per tile."""
     if S == 1 and n_pad * d_pad * itemsize <= RESIDENT_BUDGET:
         return n_pad
-    t_max = max(128, (TILE_BUDGET // max(d_pad * itemsize, 1)) // 128 * 128)
+    t_max = max(LANES,
+                (TILE_BUDGET // max(d_pad * itemsize, 1)) // LANES * LANES)
     tiles = -(-n_pad // t_max)
     return _granule(-(-n_pad // tiles))
 
@@ -109,15 +117,17 @@ def fused_trajectory(x, y, mask, w0, u, *, link: str, reg: float, eta: float,
         return trajectory_ref(x, y, mask, w0r, ur, invn, link=link, eta=eta,
                               reg=reg, anchor_scale=anchor_scale, steps=steps)
 
-    d_pad, n_pad = _granule(d), _granule(n)
+    # features on sublanes: pad d to the dtype's sublane granule only
+    d_pad = _granule(d, 8 * max(1, 4 // x.dtype.itemsize))
+    n_pad = _granule(n)
     if row_tile is None:
         row_tile = _pick_row_tile(S, n_pad, d_pad, x.dtype.itemsize)
     n_pad = _granule(n_pad, row_tile)
-    xp = _pad_axis(_pad_axis(x, n_pad, 1), d_pad, 2).reshape(S * n_pad, d_pad)
+    xt = jnp.swapaxes(_pad_axis(_pad_axis(x, n_pad, 1), d_pad, 2), 1, 2)
     yp = _pad_axis(y, n_pad, 1)[:, None, :]
     mp = _pad_axis(mask, n_pad, 1)[:, None, :]
     w_traj, r_traj = trajectory_pallas(
-        xp, yp, mp, _pad_axis(w0r, d_pad, 1), _pad_axis(ur, d_pad, 1), invn,
-        link=link, eta=eta, reg=reg, anchor_scale=anchor_scale, steps=steps,
-        row_tile=row_tile, interpret=interpret)
-    return w_traj[:, :d], r_traj[:, :d]
+        xt, yp, mp, _pad_axis(w0r.T, d_pad, 0), _pad_axis(ur.T, d_pad, 0),
+        invn, link=link, eta=eta, reg=reg, anchor_scale=anchor_scale,
+        steps=steps, row_tile=row_tile, interpret=interpret)
+    return w_traj[:, :d, 0], r_traj[:, :d, 0]

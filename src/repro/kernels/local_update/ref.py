@@ -1,10 +1,14 @@
 """Op-identical jnp oracle for the fused local-trajectory kernel.
 
 Mirrors ``local_update.py`` operation for operation — same ``link_coeff``
-coefficients, same row-vector ``dot_general`` contractions, same cast
-points, same emit expression — so a single-row-tile interpret-mode kernel
-run agrees with this reference to f32 reordering noise (≤ 1 ulp; pinned in
-tests/test_local_update).
+coefficients, same multiply-and-reduce contractions (the logits a sum of
+x·w over the features, the gradient a sum of x·c over the rows; no dot),
+same cast points, same emit expression — so an interpret-mode kernel run
+agrees with this reference to f32 reordering noise (pinned in
+tests/test_local_update).  The kernel sums in its own order (features by
+sublane, rows by 128-lane group, then across lanes); the oracle keeps the
+row-major design the round cores hold, so it never pays the kernel's
+layout copy.
 
 It doubles as the CPU executor of ``local_impl="pallas"`` (see ops.py):
 like the quant codec, interpret-mode Pallas inside a vmapped round core
@@ -18,21 +22,17 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.local_update.local_update import PRECISION, link_coeff
+from repro.kernels.local_update.local_update import link_coeff
 
 
-def _row_dot(a, b):
-    """[1, k] · [n, k]ᵀ → [1, n]  (the kernel's forward contraction)."""
-    return jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), precision=PRECISION,
-        preferred_element_type=a.dtype)
+def _logits(w, x):
+    """[1, d] against [n, d] → [1, n]  (the kernel's forward reduction)."""
+    return jnp.sum(x * w, axis=1)[None, :]
 
 
-def _col_dot(c, x):
-    """[1, n] · [n, d] → [1, d]  (the kernel's backward accumulation)."""
-    return jax.lax.dot_general(
-        c, x, (((1,), (0,)), ((), ())), precision=PRECISION,
-        preferred_element_type=c.dtype)
+def _grad(c, x):
+    """[1, n] against [n, d] → [1, d]  (the kernel's backward reduction)."""
+    return jnp.sum(x * c[0][:, None], axis=0, keepdims=True)
 
 
 def trajectory_ref(x, y, mask, w0, u, invn, *, link: str, eta: float,
@@ -57,17 +57,17 @@ def trajectory_ref(x, y, mask, w0, u, invn, *, link: str, eta: float,
     anchor = anchor_scale == 1.0
 
     def residual(w, xs, ys, ms, c_anc):
-        c = link_coeff(link, _row_dot(w, xs), ys, ms)
+        c = link_coeff(link, _logits(w, xs), ys, ms)
         if anchor:
             c = c - c_anc
-        return _col_dot(c, xs) * inv + reg * w + uc
+        return _grad(c, xs) * inv + reg * w + uc
 
     if S == 1:
         xs, ys, ms = xc[0], yc[0], mc[0]
         # resident design: the anchor coefficients are step-invariant —
         # computed once here, recomputed (bit-identically) per tile visit
         # by the kernel
-        c_anc = link_coeff(link, _row_dot(w0c, xs), ys, ms) if anchor else None
+        c_anc = link_coeff(link, _logits(w0c, xs), ys, ms) if anchor else None
 
         def step(w, _):
             r = residual(w, xs, ys, ms, c_anc)
@@ -78,7 +78,7 @@ def trajectory_ref(x, y, mask, w0, u, invn, *, link: str, eta: float,
 
         def step(w, blk):
             xs, ys, ms = blk
-            c_anc = (link_coeff(link, _row_dot(w0c, xs), ys, ms)
+            c_anc = (link_coeff(link, _logits(w0c, xs), ys, ms)
                      if anchor else None)
             r = residual(w, xs, ys, ms, c_anc)
             return w - eta * r, (w.astype(out_dtype)[0], r.astype(out_dtype)[0])

@@ -122,6 +122,7 @@ class TestFusedKernelParity:
         (300, 54, None),      # ragged → padded, auto tile
         (1000, 54, 128),      # multi-tile: accumulator crosses 8 row tiles
         (384, 200, 128),      # ragged d, multi-tile
+        (5_810, 54, None),    # a covtype K=100 client: d 54 -> 56 sublanes
     ])
     def test_padded_and_tiled_allclose(self, n, d, row_tile):
         rng = np.random.default_rng(n + d)
@@ -136,6 +137,38 @@ class TestFusedKernelParity:
                                    rtol=1e-5, atol=1e-6)
         np.testing.assert_allclose(np.asarray(rk), np.asarray(rr),
                                    rtol=1e-5, atol=1e-6)
+
+    def test_kernel_body_has_no_dot(self):
+        """Every contraction of the kernel is an M=1 matrix-vector product,
+        which the matrix unit would run at 1/128 of its rows: the body
+        multiplies and reduces on the vector unit instead.  Walk the
+        kernel's inner jaxpr (loop bodies included) and pin that no
+        dot_general is left, and that the reductions are there."""
+        from repro.kernels.local_update import trajectory_pallas
+
+        S, d8, n, steps = 1, 56, 1024, 11
+        args = (jnp.zeros((S, d8, n)), jnp.zeros((S, 1, n)),
+                jnp.ones((S, 1, n)), jnp.zeros((d8, 1)), jnp.zeros((d8, 1)),
+                jnp.ones((1, 1)))
+        outer = jax.make_jaxpr(lambda *a: trajectory_pallas(
+            *a, link="logistic", eta=1.0, reg=1e-3, anchor_scale=1.0,
+            steps=steps, row_tile=n))(*args)
+
+        def prims(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield eqn.primitive.name, eqn
+                for v in eqn.params.values():
+                    for sub in v if isinstance(v, (list, tuple)) else (v,):
+                        sub = getattr(sub, "jaxpr", sub)
+                        if hasattr(sub, "eqns"):
+                            yield from prims(sub)
+
+        kernels = [e for name, e in prims(outer.jaxpr)
+                   if name == "pallas_call"]
+        assert len(kernels) == 1
+        inner = {name for name, _ in prims(kernels[0].params["jaxpr"])}
+        assert "dot_general" not in inner
+        assert {"mul", "reduce_sum"} <= inner, inner
 
     def test_vmapped_over_clients(self):
         """The round cores vmap the per-client call — kernel must match the
