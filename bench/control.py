@@ -134,11 +134,12 @@ def init(d: int, dtype):
 
 
 def as_program(inputs, config: dict, traffic: dict, dtype):
-    """(init, runner) of the control for the harness's job loop."""
+    """(init, runner) of the control for the harness's job loop, over the
+    client blocks and w* of ``bench/models/logreg.py``'s inputs."""
     hp = traffic["hparams"]
-    round_fn = make_round(inputs.x, inputs.y, config["gamma"], hp["eta"],
-                          hp["local_epochs"], dtype)
+    round_fn = make_round(inputs.data.x, inputs.data.y, config["gamma"],
+                          hp["eta"], hp["local_epochs"], dtype)
     runner = make_runner(round_fn, traffic["chunk"],
-                         np.asarray(inputs.w_star, np.float32),
+                         np.asarray(inputs.reference, np.float32),
                          traffic["target_rel_error"])
     return init(config["d"], dtype), runner

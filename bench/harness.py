@@ -2,13 +2,16 @@
 
 A cell names a configuration (``bench/configs/<file>.json``: the dataset and
 model) and a traffic mix (``bench/traffic/<name>.json``: how the data is split
-over clients and how each training job runs). A run builds the cell's data and
-its float64 reference, builds the program's round engine once, runs one whole
-job as warm-up, and then runs jobs back to back for the window's seconds. Each
-job starts from ``init_state`` with its own key drawn from the run's seed and
-runs through ``run_rounds`` on the one compiled runner until the in-graph stop
-(rel-error at the cell's target against w*) fires or the round budget is
-spent. Every metric is a reader in ``bench/metrics/<name>.py``.
+over clients and how each training job runs). The configuration's model is a
+module of its own, ``bench/models/<model>.py`` (``model_module``): it makes
+the cell's data and its reference, the program's problem and stop, and checks
+the jobs' answers. A run builds those inputs, builds the program's round
+engine once (``bench/runtimes/<runtime>.py``), runs one whole job as warm-up,
+and then runs jobs back to back for the window's seconds. Each job starts
+from ``init_state`` with its own key drawn from the run's seed and runs
+through ``run_rounds`` on the one compiled runner until the model's stop
+fires or the round budget is spent. Every metric is a reader in
+``bench/metrics/<name>.py``.
 
 Nothing here compiles for or touches a device until ``build_program``.
 """
@@ -24,16 +27,12 @@ from pathlib import Path
 
 import numpy as np
 
-from bench.data import iid_split, make_dataset
-from bench.reference import newton_solve, rel_error
-
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 #: JAX's persistent compilation cache: a fixed path inside the checkout
 CACHE_DIR = BENCH / ".jax_cache"
-#: the scopes the round code puts on its phases (jax.named_scope)
-PHASE_SCOPES = ("fl.cohort_plan", "fl.cohort_gather", "fl.local_trajectory",
-                "fl.aa_step", "fl.uplink", "fl.psum", "fl.scatter")
+#: one module per model, ``<model>.py`` (``model_module``)
+MODELS = BENCH / "models"
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
@@ -80,31 +79,57 @@ def load_cell(name: str, spec: dict | None = None) -> Cell:
 
 
 # --------------------------------------------------------------------------
-# inputs and the reference
+# the model: inputs, reference and check
 # --------------------------------------------------------------------------
 
 @dataclasses.dataclass
 class Inputs:
-    x: np.ndarray         # [K, n_k, d] float32 client blocks
-    y: np.ndarray         # [K, n_k] float32 labels in {-1, +1}
-    w_star: np.ndarray    # [d] float64 optimum over the clients' rows
-    data_s: float
-    reference_s: float
+    data: object          # the model module's data (the clients' blocks)
+    reference: object     # the model module's reference (logreg: w*)
+    data_s: float         # seconds to make the data
+    reference_s: float    # seconds to make the reference
 
 
-def make_inputs(config: dict, traffic: dict) -> Inputs:
-    """The configuration's dataset, split IID over the traffic's clients, and
-    the float64 optimum of the objective over the rows the clients hold."""
-    if config["model"] != "logreg":
-        raise ValueError(f"no inputs for model {config['model']!r}")
-    t0 = time.perf_counter()
-    X, y = make_dataset(config["n"], config["d"], config["pos_frac"],
-                        config["scale"], config["data_seed"])
-    xs, ys = iid_split(X, y, traffic["num_clients"], traffic["split_seed"])
-    t1 = time.perf_counter()
-    w_star = newton_solve(xs.reshape(-1, config["d"]), ys.reshape(-1),
-                          config["gamma"])
-    return Inputs(xs, ys, w_star, t1 - t0, time.perf_counter() - t1)
+def model_module(config: dict):
+    """The module of the configuration's model, ``bench/models/<model>.py``.
+
+    It defines:
+
+    * ``make_inputs(config, traffic) -> Inputs``: the cell's data from the
+      configuration and the traffic (never from the run's seed), and what
+      of the reference is made before the window (logreg: w*, which its stop
+      reads too), by code that imports nothing of the program; both timed,
+      as set-up. A reference that needs the chip runs in ``check`` instead,
+      after the window, once the program's state is freed.
+    * ``problem(config, inputs)``: the program's ``FLProblem`` over the data.
+    * ``stop(config, traffic, inputs) -> dict``: the keyword arguments of the
+      runner's in-graph stop (``make_chunk_runner``); ``{}`` runs every job
+      to the traffic's ``round_budget``.
+    * ``answer(state)``: the small host value a job keeps from its final
+      state, fetched once a job (under ``bench.job_end``); never the whole
+      weights of a large model.
+    * ``reached(trace) -> bool``: the job's answer came (its stop fired, or
+      its fixed rounds ran), from the engine's ``RoundTrace``.
+    * ``check(jobs, inputs, traffic) -> dict``: ``{name: {"value", "limit"}}``
+      over the jobs' answers against the reference; the run is correct when
+      every value is finite and at most its limit.
+    """
+    path = MODELS / f"{config['model']}.py"
+    if not path.is_file():
+        raise SystemExit(f"no module for model {config['model']!r}: add "
+                         f"{path}")
+    return load_module(path)
+
+
+def make_inputs(cell: Cell) -> Inputs:
+    """The cell's data and reference, made by its model's module."""
+    return model_module(cell.config).make_inputs(cell.config, cell.traffic)
+
+
+def check_jobs(cell: Cell, jobs: list, inputs: Inputs) -> dict:
+    """The jobs' answers against the reference, by the cell's model's
+    module: each number compared beside its limit."""
+    return model_module(cell.config).check(jobs, inputs, cell.traffic)
 
 
 # --------------------------------------------------------------------------
@@ -114,10 +139,13 @@ def make_inputs(config: dict, traffic: dict) -> Inputs:
 @dataclasses.dataclass
 class Program:
     """What the job loop drives: ``init(key)`` makes a job's first state,
-    ``runner`` is the compiled chunk runner (the engine's interface)."""
+    ``runner`` is the compiled chunk runner (the engine's interface),
+    ``model`` the model module that reads a job's answer and whether it
+    came."""
 
     init: object
     runner: object
+    model: object
     round_fn: object = None
 
     def hlo_texts(self, chunk: int) -> list:
@@ -138,8 +166,8 @@ def build_program(cell: Cell, inputs: Inputs, devices: list) -> Program:
 class Job:
     rounds: int           # live rounds (rows of the run's trace)
     slots: int            # rounds computed, with those past the stop
-    reached: bool         # the target stop fired
-    params: np.ndarray    # the job's model, float32 [d]
+    reached: bool         # the job's answer came (model.reached)
+    answer: object        # the job's answer (model.answer)
 
 
 def _annotate(name: str, on: bool):
@@ -171,11 +199,11 @@ def run_job(prog: Program, traffic: dict, key: int,
     state, trace = run_rounds(prog.round_fn, state, traffic["round_budget"],
                               chunk=traffic["chunk"], runner=runner)
     with _annotate("bench.job_end", spans):
-        params = np.asarray(jax.device_get(state.params))
+        answer = prog.model.answer(state)
     chunk = traffic["chunk"]
     return Job(rounds=trace.num_rounds,
                slots=chunk * -(-trace.num_rounds // chunk),
-               reached=bool(trace.stopped), params=params)
+               reached=bool(prog.model.reached(trace)), answer=answer)
 
 
 def job_keys(seed: int):
@@ -243,17 +271,6 @@ def run_window(prog: Program, traffic: dict, keys, seconds: float,
 # correctness
 # --------------------------------------------------------------------------
 
-def check_jobs(jobs: list, w_star: np.ndarray, traffic: dict) -> dict:
-    """Every job's model against the float64 reference, each number beside
-    its limit: the worst relative error, and the jobs whose answer never
-    came (the budget spent short of the target)."""
-    worst = max(rel_error(j.params, w_star) for j in jobs)
-    return {"rel_error_max": {"value": worst,
-                              "limit": traffic["rel_error_limit"]},
-            "jobs_short_of_target": {"value": sum(not j.reached for j in jobs),
-                                     "limit": 0}}
-
-
 def is_correct(checks: dict) -> bool:
     return all(np.isfinite(c["value"]) and c["value"] <= c["limit"]
                for c in checks.values())
@@ -320,9 +337,13 @@ class Context:
 
 
 def load_module(path: Path):
+    """The module at ``path``, registered in ``sys.modules`` under a name
+    made from its directory and stem (so that a dataclass in it resolves its
+    own module)."""
     spec = importlib.util.spec_from_file_location(
         f"bench_{path.parent.name}_{path.stem.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod
     spec.loader.exec_module(mod)
     return mod
 

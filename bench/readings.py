@@ -53,19 +53,20 @@ def read_witness(cell, inputs, dtype, precision: str) -> dict:
     with jax.default_matmul_precision(precision):
         init, runner = control.as_program(inputs, cell.config, traffic, dtype)
         t0 = time.perf_counter()
-        jobs, _ = H.run_window(H.Program(init, runner), traffic,
-                               H.job_keys(0), 0.0, max_jobs=1)
+        jobs, _ = H.run_window(
+            H.Program(init, runner, H.model_module(cell.config)), traffic,
+            H.job_keys(0), 0.0, max_jobs=1)
     return {"dtype": str(np.dtype(dtype)),
             "precision": precision, "rounds": jobs[0].rounds,
             "reached": jobs[0].reached, "seconds": time.perf_counter() - t0,
             **{k: v["value"] for k, v in
-               H.check_jobs(jobs, inputs.w_star, cell.traffic).items()}}
+               H.check_jobs(cell, jobs, inputs).items()}}
 
 
 def read_window(prog, cell, inputs, seed: int, seconds: float) -> dict:
     jobs, window_s = H.run_window(prog, cell.traffic, H.job_keys(seed),
                                   seconds)
-    checks = H.check_jobs(jobs, inputs.w_star, cell.traffic)
+    checks = H.check_jobs(cell, jobs, inputs)
     return {"seed": seed, "jobs": len(jobs),
             "failed": sum(not j.reached for j in jobs),
             "rounds": [j.rounds for j in jobs], "window_s": window_s,
@@ -90,7 +91,7 @@ def main(argv=None) -> int:
     if devices[0].platform == "cpu" or len(devices) < cell.chips:
         H.log("readings: needs the cell's chips on an accelerator")
         return 2
-    inputs = H.make_inputs(cell.config, cell.traffic)
+    inputs = H.make_inputs(cell)
     prog = H.build_program(cell, inputs, devices[:cell.chips])
     H.warm_up(prog, cell.traffic, 0)
     out = {"workload": cell.name, "program": [], "control": []}
@@ -102,7 +103,7 @@ def main(argv=None) -> int:
     if args.control_seeds:
         init, runner = control.as_program(inputs, cell.config, cell.traffic,
                                           jnp.bfloat16)
-        ctl = H.Program(init, runner)
+        ctl = H.Program(init, runner, H.model_module(cell.config))
         H.warm_up(ctl, cell.traffic, 0)
         for seed in args.control_seeds:
             r = read_window(ctl, cell, inputs, seed, args.seconds)

@@ -76,7 +76,7 @@ def traced_window(prog, cell, keys, devices):
         jax.profiler.stop_trace()
         scope_of = T.scope_map(prog.hlo_texts(cell.traffic["chunk"]))
         summary = T.reduce(T.find_xplane(tdir), [d.id for d in devices],
-                           H.PHASE_SCOPES, scope_of)
+                           scope_of)
     finally:
         shutil.rmtree(tdir, ignore_errors=True)
     return jobs, window_s, summary
@@ -91,7 +91,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices: list,
     if peaks is None:
         peaks = H.load_peaks(devices[0].device_kind)
     counter = H.CompileCounter()
-    inputs = H.make_inputs(cell.config, cell.traffic)
+    inputs = H.make_inputs(cell)
     prog = H.build_program(cell, inputs, devices)
     keys = H.job_keys(seed)
     compile_s, warm_s = H.warm_up(prog, cell.traffic, next(keys))
@@ -116,7 +116,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices: list,
 
     ctx = H.Context(cell, jobs, window_s, setup_s, peaks, summary)
     metrics = H.read_metrics(cell.per_layer if trace else cell.end_to_end, ctx)
-    checks = H.check_jobs(jobs, inputs.w_star, cell.traffic)
+    checks = H.check_jobs(cell, jobs, inputs)
     result = {
         "correct": H.is_correct(checks),
         "attempted": len(jobs),

@@ -42,7 +42,7 @@ def test_round_is_the_sum_and_other_algorithms_are_not_counted():
 
 def _ctx(phase_s, chips=1, slots=10):
     cell = H.Cell("c", chips, CONFIG, TRAFFIC, [], [])
-    jobs = [H.Job(rounds=slots - 1, slots=slots, reached=True, params=None)]
+    jobs = [H.Job(rounds=slots - 1, slots=slots, reached=True, answer=None)]
     trace = types.SimpleNamespace(phase_s=phase_s)
     peaks = {"bf16_flops_per_s": 1e9, "hbm_bytes_per_s": 1e9}
     return H.Context(cell, jobs, 1.0, 0.0, peaks, trace)

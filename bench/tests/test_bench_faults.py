@@ -7,8 +7,9 @@ taken over the rest; and the model answer altered where the round produces
 it. The cells run on one chip, so there is no exchange between chips to
 leave out.
 """
+import dataclasses
+
 import jax
-import jax.numpy as jnp
 import pytest
 
 from bench import harness as H
@@ -16,57 +17,62 @@ from bench import run
 from bench.tests import tiny
 
 
-def _frozen(prog, cell, inputs):
+def _rewrapped(prog, cell, inputs, round_fn):
+    """``prog`` with ``round_fn`` in place of its round, under the runner
+    and stop the model gives it."""
     from repro.core import make_chunk_runner
 
+    runner = make_chunk_runner(
+        round_fn, cell.traffic["chunk"],
+        **prog.model.stop(cell.config, cell.traffic, inputs))
+    return dataclasses.replace(prog, runner=runner, round_fn=round_fn)
+
+
+def frozen(prog, cell, inputs):
     def round_fn(state):
         _, metrics = prog.round_fn(state)
         return state, metrics
 
-    runner = make_chunk_runner(
-        round_fn, cell.traffic["chunk"],
-        w_star=jnp.asarray(inputs.w_star, jnp.float32),
-        stop_rel_error=cell.traffic["target_rel_error"])
-    return H.Program(prog.init, runner, round_fn)
+    return _rewrapped(prog, cell, inputs, round_fn)
 
 
 def _altered(prog, cell, inputs):
-    from repro.core import make_chunk_runner
-
     def round_fn(state):
         new, metrics = prog.round_fn(state)
         return new._replace(params=new.params.at[0].add(1e-2)), metrics
 
-    runner = make_chunk_runner(
-        round_fn, cell.traffic["chunk"],
-        w_star=jnp.asarray(inputs.w_star, jnp.float32),
-        stop_rel_error=cell.traffic["target_rel_error"])
-    return H.Program(prog.init, runner, round_fn)
+    return _rewrapped(prog, cell, inputs, round_fn)
 
 
-FAULTS = {"state_unchanged": _frozen, "answer_altered": _altered}
+def _half_clients(inputs):
+    K = inputs.data.x.shape[0] // 2
+    data = inputs.data._replace(x=inputs.data.x[:K], y=inputs.data.y[:K])
+    return dataclasses.replace(inputs, data=data, data_s=0, reference_s=0)
 
 
-def _run(monkeypatch, fault: str) -> dict:
+FAULTS = {"state_unchanged": frozen, "answer_altered": _altered}
+
+
+def run_broken(monkeypatch, cell, fault: str) -> dict:
+    """``run.run_cell`` of ``cell`` with ``fault`` planted in the program
+    the runtime builds."""
     build = H.build_program
 
     def broken(cell, inputs, devices):
         if fault == "half_clients":
-            K = inputs.x.shape[0] // 2
-            half = H.Inputs(inputs.x[:K], inputs.y[:K], inputs.w_star, 0, 0)
-            return build(cell, half, devices)
+            return build(cell, _half_clients(inputs), devices)
         if fault == "none":
             return build(cell, inputs, devices)
         return FAULTS[fault](build(cell, inputs, devices), cell, inputs)
 
     monkeypatch.setattr(H, "build_program", broken)
-    return run.run_cell(tiny.cell(round_budget=30), 2**31 + 41, 0.0, False,
-                        jax.devices()[:1], 0.0, H.load_peaks("TPU v5 lite"))
+    return run.run_cell(cell, 2**31 + 41, 0.0, False, jax.devices()[:1], 0.0,
+                        H.load_peaks("TPU v5 lite"))
 
 
 @pytest.mark.parametrize("fault", ["none", "state_unchanged", "half_clients",
                                    "answer_altered"])
 def test_fault_makes_the_run_not_correct(monkeypatch, fault):
-    result = _run(monkeypatch, fault)
+    result = run_broken(monkeypatch, tiny.cell(round_budget=30), fault)
     assert list(result)[-1] == "checks"
     assert result["correct"] is (fault == "none"), result["checks"]

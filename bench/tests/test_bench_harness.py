@@ -11,7 +11,7 @@ from bench.tests import tiny
 @pytest.fixture(scope="module")
 def built():
     cell = tiny.cell()
-    inputs = H.make_inputs(cell.config, cell.traffic)
+    inputs = H.make_inputs(cell)
     prog = H.build_program(cell, inputs, jax.devices()[:1])
     keys = H.job_keys(2**31 + 977)
     H.warm_up(prog, cell.traffic, next(keys))
@@ -27,7 +27,7 @@ def test_jobs_reach_the_target_against_the_reference(built):
     assert all(j.slots % cell.traffic["chunk"] == 0 for j in jobs)
     assert all(j.rounds <= j.slots < j.rounds + cell.traffic["chunk"]
                for j in jobs)
-    checks = H.check_jobs(jobs, inputs.w_star, cell.traffic)
+    checks = H.check_jobs(cell, jobs, inputs)
     assert H.is_correct(checks)
     assert checks["rel_error_max"]["value"] < 1.01e-4
 
@@ -56,6 +56,18 @@ def test_job_keys_repeat_for_a_seed_over_32_bits():
     assert ka != [next(H.job_keys(2**31 + 6)) for _ in range(4)]
 
 
+def _counts_read(per_layer: list) -> set:
+    """The phases of bench/counts that the cell's per-layer metrics read:
+    ``roofline.<phase>`` reads ``<phase>``, ``mfu`` reads ``round``."""
+    need = set()
+    for m in per_layer:
+        if m["name"].startswith("roofline."):
+            need.add(m["name"].removeprefix("roofline."))
+        elif m["name"] == "mfu":
+            need.add("round")
+    return need
+
+
 def test_every_cell_loads_with_its_files():
     spec = H.load_json(H.ROOT / "BENCHMARK.json")
     for w in spec["workloads"]:
@@ -65,11 +77,22 @@ def test_every_cell_loads_with_its_files():
             assert (H.BENCH / "metrics" / f"{m['name']}.py").is_file()
         assert (H.BENCH / "runtimes"
                 / f"{cell.traffic['runtime']}.py").is_file()
+        assert (H.MODELS / f"{cell.config['model']}.py").is_file()
         # every count the cell's rooflines and mfu read exists
         ctx = H.Context(cell, [], 0.0, 0.0, H.load_peaks("TPU v5 lite"))
-        assert {"local_trajectory", "aa_step", "round"} <= set(ctx.work())
+        assert _counts_read(cell.per_layer) <= set(ctx.work())
 
 
 def test_unknown_device_kind_is_an_error():
     with pytest.raises(SystemExit):
         H.load_peaks("TPU v99")
+
+
+def test_a_loaded_module_may_define_a_dataclass(tmp_path):
+    path = tmp_path / "with_dataclass.py"
+    path.write_text("from __future__ import annotations\n"
+                    "import dataclasses\n\n"
+                    "@dataclasses.dataclass\n"
+                    "class Blocks:\n"
+                    "    rows: int\n")
+    assert H.load_module(path).Blocks(3).rows == 3

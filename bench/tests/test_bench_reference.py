@@ -44,11 +44,12 @@ def test_rel_error():
 
 def _control_run(dtype):
     cell = tiny.cell(round_budget=40)
-    inputs = H.make_inputs(cell.config, cell.traffic)
+    inputs = H.make_inputs(cell)
     init, runner = control.as_program(inputs, cell.config, cell.traffic, dtype)
-    jobs, _ = H.run_window(H.Program(init, runner), cell.traffic,
-                           H.job_keys(7), 0.0, max_jobs=1)
-    return jobs, H.check_jobs(jobs, inputs.w_star, cell.traffic)
+    prog = H.Program(init, runner, H.model_module(cell.config))
+    jobs, _ = H.run_window(prog, cell.traffic, H.job_keys(7), 0.0,
+                           max_jobs=1)
+    return jobs, H.check_jobs(cell, jobs, inputs)
 
 
 def test_reference_algorithm_at_float32_reaches_the_target():

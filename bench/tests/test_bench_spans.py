@@ -79,7 +79,7 @@ def test_kernel_map_finds_kernels_by_their_pallas_name():
 
 def _ctx(summary, slots: int):
     cell = SimpleNamespace(chips=1)
-    jobs = [H.Job(rounds=slots, slots=slots, reached=True, params=None)]
+    jobs = [H.Job(rounds=slots, slots=slots, reached=True, answer=None)]
     return H.Context(cell, jobs, 1.0, 0.0, {}, summary)
 
 
@@ -94,8 +94,7 @@ def test_kernel_reader_reads_the_named_kernel_without_the_padding_copy():
 
 def test_kernel_reader_is_silent_on_a_program_without_kernel_names():
     scope_of = json.loads((DATA / "covtype-k100.scopes.json").read_text())
-    summary = T.reduce(str(DATA / "covtype-k100.xplane.pb"), [0],
-                       ("fl.local_trajectory",), scope_of)
+    summary = T.reduce(str(DATA / "covtype-k100.xplane.pb"), [0], scope_of)
     assert _reader().read(_ctx(summary, 15)) is None
 
 
@@ -151,8 +150,7 @@ def test_recorded_kernel_is_found_by_name(recorded):
     trajectory = summ.kernel_s["fl_local_trajectory_kernel"]
     assert 0 < trajectory < summ.busy_s
     # the reader reads the same instructions from bench/trace.py's summary
-    summary = T.reduce(str(SPANS_TRACE), [0], ("fl.local_trajectory",),
-                       maps["scope_of"])
+    summary = T.reduce(str(SPANS_TRACE), [0], maps["scope_of"])
     ms = _reader().read(_ctx(summary, 15))
     assert ms == pytest.approx(1e3 * trajectory / 15, rel=1e-6)
     assert ms < 1e3 * summary.phase_s["fl.local_trajectory"][0] / 15

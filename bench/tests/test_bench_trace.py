@@ -8,9 +8,13 @@ import pytest
 
 from bench import trace as T
 
-FIXTURE = Path(__file__).parent / "data" / "covtype-k100.xplane.pb"
-SCOPES_OF = Path(__file__).parent / "data" / "covtype-k100.scopes.json"
-SCOPES = ("fl.local_trajectory", "fl.aa_step", "fl.uplink", "fl.psum")
+DATA = Path(__file__).parent / "data"
+FIXTURE = DATA / "covtype-k100.xplane.pb"
+SCOPES_OF = DATA / "covtype-k100.scopes.json"
+#: a later recording (one covtype-k100 job) whose runner carries the scopes
+#: fl.anchor_grad, fl.round_metrics and fl.stop_check as well
+SPANS_FIXTURE = DATA / "covtype-k100-spans.xplane.pb"
+SPANS_MAPS = DATA / "covtype-k100-spans.maps.json"
 
 
 def test_union_merges_overlaps_and_clip_keeps_the_window():
@@ -52,13 +56,32 @@ def test_scope_map_takes_the_innermost_phase_of_each_instruction():
 @pytest.fixture(scope="module")
 def summary():
     scope_of = json.loads(SCOPES_OF.read_text())
-    return T.reduce(str(FIXTURE), [0], SCOPES, scope_of)
+    return T.reduce(str(FIXTURE), [0], scope_of)
 
 
 def test_recorded_trace_finds_the_round_phases(summary):
     for scope in ("fl.local_trajectory", "fl.aa_step", "fl.uplink"):
         assert scope in summary.phase_s and summary.phase_s[scope][0] > 0
     assert "fl.psum" not in summary.phase_s      # one chip, vmap runtime
+    # the phases are the scopes the runner's HLO carries, and no others
+    scopes = set(json.loads(SCOPES_OF.read_text()).values())
+    assert set(summary.phase_s) == scopes
+
+
+def test_every_scope_in_the_runner_is_a_phase():
+    scope_of = json.loads(SPANS_MAPS.read_text())["scope_of"]
+    summary = T.reduce(str(SPANS_FIXTURE), [0], scope_of)
+    for scope in ("fl.local_trajectory", "fl.aa_step", "fl.anchor_grad",
+                  "fl.round_metrics", "fl.stop_check"):
+        assert summary.phase_s[scope][0] > 0
+    # each op counts towards its innermost scope alone
+    assert sum(v[0] for v in summary.phase_s.values()) <= summary.busy_s
+    # a scope of any name is read: rename one and its time follows it
+    renamed = {k: "fl.new_phase" if v == "fl.anchor_grad" else v
+               for k, v in scope_of.items()}
+    again = T.reduce(str(SPANS_FIXTURE), [0], renamed)
+    assert again.phase_s["fl.new_phase"] == summary.phase_s["fl.anchor_grad"]
+    assert "fl.anchor_grad" not in again.phase_s
 
 
 def test_recorded_trace_times_fit_in_the_window(summary):

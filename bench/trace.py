@@ -8,12 +8,15 @@ Reads the ``.xplane.pb`` the JAX profiler writes, with
 * busy seconds: the union of the intervals in which an XLA operation ran on
   the chip (the trace's "XLA Ops" line), inside the window, averaged over the
   chips;
-* device seconds per round phase: the union of the intervals of the operations
-  whose op metadata carries the phase's ``jax.named_scope`` (``fl.*``), per
-  chip. A device event names only its HLO instruction, so the phase comes from
-  the compiled runner's HLO text (``scope_map``): the innermost ``fl.*`` scope
-  of the instruction's ``op_name``, looked up for the events that ran inside
-  the runner's module (``jit_chunk_fn``);
+* device seconds per round phase, for every ``fl.*`` scope
+  (``jax.named_scope``) that occurs in the compiled runner: the union of the
+  intervals of the operations whose phase it is, per chip. A device event
+  names only its HLO instruction, so the phase comes from the compiled
+  runner's HLO text (``scope_map``): the innermost ``fl.*`` scope of the
+  instruction's ``op_name``, looked up for the events that ran inside the
+  runner's module (``jit_chunk_fn``). An operation counts towards its
+  innermost scope alone, so an enclosing scope's time leaves out what its
+  inner scopes ran;
 * the device operations that took most time (leaf operations, summed by
   phase and name over the chips), and the device's idle gaps labelled by the
   benchmark host span that overlaps each most (``host`` where none does: the
@@ -158,8 +161,9 @@ def _leaves(ops: list) -> list:
     return out
 
 
-def reduce(path: str, device_ids: list, scopes: tuple,
-           scope_of: dict) -> TraceSummary:
+def reduce(path: str, device_ids: list, scope_of: dict) -> TraceSummary:
+    """The trace at ``path`` over the chips ``device_ids``, with the phase of
+    each runner instruction from ``scope_of`` (``scope_map``)."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
@@ -170,6 +174,7 @@ def reduce(path: str, device_ids: list, scopes: tuple,
         raise ValueError(f"{path}: no bench.job_init/bench.job_end spans")
     lo, hi = min(starts), max(ends)
     window_ns = hi - lo
+    scopes = sorted(set(scope_of.values()))
     busy, phase = [], {s: [] for s in scopes}
     op_time: dict = {}
     first_busy = None
