@@ -46,9 +46,19 @@ class ArchConfig:
     ssm_conv_width: int = 4
     # --- hybrid (zamba2-style shared attention block) ---
     shared_attn_period: int = 0   # apply the weight-tied attn block every Nth layer
+    # --- hybrid by pattern (granite-4.0-h): one kind per layer, "mamba" or
+    # "attention", each layer followed by its own SwiGLU MLP ---
+    layer_types: tuple = ()
+    # --- muP multipliers (granite): 0 / 1 keep the plain transformer ---
+    embedding_multiplier: float = 0.0   # 0 => sqrt(d_model)
+    residual_multiplier: float = 1.0    # h + m·mixer(...), h + m·mlp(...)
+    attention_multiplier: float = 0.0   # softmax scale; 0 => 1/sqrt(head_dim)
+    logits_scaling: float = 1.0         # logits = h·headᵀ / logits_scaling
+    norm_eps: float = 1e-6
     # --- attention variant ---
     sliding_window: int = 0       # 0 = full causal; >0 = window size
     rope_theta: float = 10_000.0
+    position_embedding: str = "rope"  # "rope" | "nope" (no positional encoding)
     # --- modality frontend stub (vlm/audio): embeddings arrive precomputed ---
     frontend_tokens: int = 0      # patches / audio frames per sample
     # --- serving options ---
@@ -109,6 +119,11 @@ class ArchConfig:
         if self.family in ("ssm",):
             per = self._ssm_layer_params()
             n += L * per
+        elif self.layer_types:
+            n_attn = self.layer_types.count("attention")
+            mlp = 3 * d * self.d_ff
+            n += (L - n_attn) * (self._ssm_layer_params() + mlp)
+            n += n_attn * (self._attn_layer_params() + mlp)
         elif self.family == "hybrid":
             n_shared = self.num_layers // max(self.shared_attn_period, 1)
             n_mamba = L - n_shared
@@ -149,12 +164,15 @@ class ArchConfig:
     def reduced(self) -> "ArchConfig":
         """Smoke-test variant: same family/block structure, toy size."""
         d = min(self.d_model, 256)
+        # one layer of each kind, in the order the pattern first uses them
+        kinds = tuple(dict.fromkeys(self.layer_types))
         heads = min(self.num_heads, 4) if self.num_heads else 0
         kv = min(self.num_kv_heads, max(1, heads // 2)) if self.num_kv_heads else 0
         return dataclasses.replace(
             self,
             name=self.name + "-reduced",
-            num_layers=2,
+            num_layers=len(kinds) if kinds else 2,
+            layer_types=kinds,
             d_model=d,
             num_heads=heads,
             num_kv_heads=kv or heads,
@@ -211,7 +229,13 @@ class ArchConfig:
         if self.family in ("ssm", "hybrid"):
             assert self.ssm_state > 0
             assert self.d_inner % self.ssm_head_dim == 0
-        if self.family == "hybrid":
+        if self.layer_types:
+            assert len(self.layer_types) == self.num_layers
+            assert set(self.layer_types) <= {"mamba", "attention"}
+            assert self.d_ff > 0
+            assert "attention" not in self.layer_types or self.num_heads > 0
+        elif self.family == "hybrid":
             assert self.shared_attn_period > 0 and self.num_heads > 0
+        assert self.position_embedding in ("rope", "nope")
         if self.num_heads:
             pass  # head_dim may differ from d_model//heads (qwen3)

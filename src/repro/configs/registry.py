@@ -17,6 +17,7 @@ _MODULES = {
     "granite-20b": "granite_20b",
     "minicpm-2b": "minicpm_2b",
     "musicgen-medium": "musicgen_medium",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 ARCHS = tuple(_MODULES)

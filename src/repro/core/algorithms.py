@@ -382,6 +382,7 @@ def _local_trajectory(
     w0: Pytree,
     residual_fn: Callable[[Pytree, jax.Array], Pytree],
     rng: jax.Array,
+    r0: Pytree | None = None,
 ):
     """Run L corrected-GD steps from w0 and return the full trajectory.
 
@@ -391,6 +392,10 @@ def _local_trajectory(
     just the last scan iteration (its unused w_{L+1} is a single axpy), so
     there is no per-leaf concatenate epilogue and no standalone r_L
     dispatch in either runtime.
+
+    ``r0`` — the residual at w0 when the caller already holds it (full-batch
+    SVRG: from the anchor gradient): the steps then start at w_1 and
+    evaluate L gradients, not L+1, and (w0, r0) heads the trajectory.
     """
     rngs = jax.random.split(rng, hp.local_epochs + 1)
 
@@ -402,23 +407,29 @@ def _local_trajectory(
                               tm.tree_axpy(-hp.eta, r, w), w)
         return w_next, (w, r)
 
-    if hp.local_impl == LOCAL_IMPL_SEED:
-        # the seed form, replayed for bench_round's baseline: scan stops at
-        # L, r_L dispatches standalone, the history is concatenated per leaf
-        L = hp.local_epochs
-        w_L, (w_hist, r_hist) = jax.lax.scan(step, w0, rngs[:L])
-        r_L = residual_fn(w_L, rngs[L])
-        w_traj = jax.tree.map(
-            lambda h, last: jnp.concatenate([h, last[None]], axis=0),
-            w_hist, w_L)
-        r_traj = jax.tree.map(
-            lambda h, last: jnp.concatenate([h, last[None]], axis=0),
-            r_hist, r_L)
-        return w_traj, r_traj
+    def steps(w, keys):
+        if hp.local_impl != LOCAL_IMPL_SEED:
+            _, traj = jax.lax.scan(step, w, keys)
+            return traj
+        # the seed form, replayed for bench_round's baseline: the scan stops
+        # one short, the last residual dispatches standalone, the history is
+        # concatenated per leaf
+        w_last, (w_hist, r_hist) = jax.lax.scan(step, w, keys[:-1])
+        r_last = residual_fn(w_last, keys[-1])
+        append = lambda hist, last: jnp.concatenate([hist, last[None]], axis=0)
+        return (jax.tree.map(append, w_hist, w_last),
+                jax.tree.map(append, r_hist, r_last))
 
     with jax.named_scope("fl.local_trajectory"):
-        _, (w_traj, r_traj) = jax.lax.scan(step, w0, rngs)
-    return w_traj, r_traj
+        if r0 is None:
+            return steps(w0, rngs)
+        w1 = jax.tree.map(lambda a, b: a.astype(b.dtype),
+                          tm.tree_axpy(-hp.eta, r0, w0), w0)
+        w_rest, r_rest = steps(w1, rngs[1:])
+        head = lambda t0, rest: jnp.concatenate(
+            [t0.astype(rest.dtype)[None], rest], axis=0)
+        return (jax.tree.map(head, w0, w_rest),
+                jax.tree.map(head, r0, r_rest))
 
 
 def _fused_trajectory(
@@ -494,11 +505,26 @@ def _make_residual_fn(
 # per-client updates (to be vmapped over the stacked client axis)
 # --------------------------------------------------------------------------
 
-def _svrg_trajectory(problem, hp, w_t, g_global, batch, rng):
+def _svrg_trajectory(problem, hp, w_t, g_global, batch, rng, g_anchor=None):
     """SVRG-corrected trajectory: fused dual-gradient kernels when resolved,
-    else the two-autodiff residual path."""
+    else the autodiff residual path. ``g_anchor`` is the client's own
+    full-batch gradient at w^t (the round's anchor gradient, before the
+    wire; computed here when not given): with full batches the correction
+    ∇f(w^t) − ∇f_k(w^t) is then one constant, the residual at w^t is
+    ∇f(w^t) itself, and the trajectory costs L gradient evaluations. A
+    minibatch step corrects with the anchor gradient on its own minibatch
+    ζ, two evaluations a step."""
     if hp.local_impl == "pallas":
         return _fused_trajectory(problem, hp, w_t, batch, 1.0, g_global, rng)
+    if hp.batch_size is None:
+        if g_anchor is None:
+            g_anchor = problem.grad(w_t, batch)
+        correction = tm.tree_sub(g_global, g_anchor)
+        residual_fn = _make_residual_fn(problem, hp, batch, correction)
+        # r_0 summed as every later residual is, not taken as ∇f(w^t): the
+        # AA Gram solve at f32 turns a last-ulp change into another run
+        r0 = tm.tree_add(g_anchor, correction)
+        return _local_trajectory(hp, w_t, residual_fn, rng, r0=r0)
 
     def svrg_correction(mb):
         # −∇f_k(w^t; ζ) + ∇f(w^t): the SAME minibatch ζ as the live gradient.
@@ -509,9 +535,10 @@ def _svrg_trajectory(problem, hp, w_t, g_global, batch, rng):
 
 
 def _client_svrg(problem, hp, use_aa, w_t, g_global, x, y, mask, rng,
-                 hist_s=None, hist_y=None, poison=None):
+                 g_anchor, hist_s=None, hist_y=None, poison=None):
     batch = ClientBatch(x, y, mask)
-    w_traj, r_traj = _svrg_trajectory(problem, hp, w_t, g_global, batch, rng)
+    w_traj, r_traj = _svrg_trajectory(problem, hp, w_t, g_global, batch, rng,
+                                      g_anchor)
     nan_st = AAStats(jnp.nan, jnp.nan, jnp.nan, jnp.array(0), jnp.array(0))
     if not use_aa:
         w_k = jax.tree.map(lambda t: t[-1], w_traj)
@@ -584,9 +611,10 @@ def _client_avg(problem, hp, use_aa, w_t, x, y, mask, rng):
     return w_k, stats
 
 
-def _client_lbfgs(problem, hp, w_t, g_global, x, y, mask, rng):
+def _client_lbfgs(problem, hp, w_t, g_global, x, y, mask, rng, g_anchor):
     batch = ClientBatch(x, y, mask)
-    w_traj, r_traj = _svrg_trajectory(problem, hp, w_t, g_global, batch, rng)
+    w_traj, r_traj = _svrg_trajectory(problem, hp, w_t, g_global, batch, rng,
+                                      g_anchor)
     s, y_stack = trajectory_to_sy(w_traj, r_traj)
     direction = lbfgs_two_loop(g_global, s, y_stack, hp.eta)
     w_k = tm.tree_sub(w_t, direction)
@@ -1010,32 +1038,30 @@ def _svrg_round_core(problem, hp, use_aa, R, w_t, x, y, mask, dweight, pweight,
     multisecant solve (see _client_svrg).
     """
     w_t = R.broadcast(w_t)
-    g_k, comm = R.uplink(_stack_grads(problem, w_t, x, y, mask), rngs,
-                         GRAD_UPLINK, state=comm)
+    # each client's own anchor gradient, before the wire, seeds its local
+    # trajectory (_svrg_trajectory)
+    g_own = _stack_grads(problem, w_t, x, y, mask)
+    g_k, comm = R.uplink(g_own, rngs, GRAD_UPLINK, state=comm)
     g_global = R.broadcast(R.wsum(dweight, g_k))
+    client = partial(_client_svrg, problem, hp, use_aa, w_t, g_global)
     if hist_s is not None and poison is not None:
         flags, fkeys = poison
         w_k, stats, new_hs, new_hy = jax.vmap(
-            lambda xx, yy, mm, rr, hs, hy, fl, fk: _client_svrg(
-                problem, hp, use_aa, w_t, g_global, xx, yy, mm, rr, hs, hy,
-                poison=(fl, fk, poison_scale))
-        )(x, y, mask, rngs, hist_s, hist_y, flags, fkeys)
+            lambda xx, yy, mm, rr, ga, hs, hy, fl, fk: client(
+                xx, yy, mm, rr, ga, hs, hy, poison=(fl, fk, poison_scale))
+        )(x, y, mask, rngs, g_own, hist_s, hist_y, flags, fkeys)
     elif hist_s is not None:
-        w_k, stats, new_hs, new_hy = jax.vmap(
-            partial(_client_svrg, problem, hp, use_aa, w_t, g_global)
-        )(x, y, mask, rngs, hist_s, hist_y)
+        w_k, stats, new_hs, new_hy = jax.vmap(client)(
+            x, y, mask, rngs, g_own, hist_s, hist_y)
     elif poison is not None:
         flags, fkeys = poison
         w_k, stats = jax.vmap(
-            lambda xx, yy, mm, rr, fl, fk: _client_svrg(
-                problem, hp, use_aa, w_t, g_global, xx, yy, mm, rr,
-                poison=(fl, fk, poison_scale))
-        )(x, y, mask, rngs, flags, fkeys)
+            lambda xx, yy, mm, rr, ga, fl, fk: client(
+                xx, yy, mm, rr, ga, poison=(fl, fk, poison_scale))
+        )(x, y, mask, rngs, g_own, flags, fkeys)
         new_hs = new_hy = None
     else:
-        w_k, stats = jax.vmap(
-            partial(_client_svrg, problem, hp, use_aa, w_t, g_global)
-        )(x, y, mask, rngs)
+        w_k, stats = jax.vmap(client)(x, y, mask, rngs, g_own)
         new_hs = new_hy = None
     w_k, comm = R.uplink(w_k, rngs, DELTA_UPLINK, anchor=w_t, state=comm)
     new_params = R.wsum(pweight, w_k, anchor=w_t)
@@ -1082,12 +1108,12 @@ def _avg_round_core(problem, hp, use_aa, R, w_t, x, y, mask, dweight, pweight,
 def _lbfgs_round_core(problem, hp, R, w_t, x, y, mask, dweight, pweight, rngs,
                       comm=None):
     w_t = R.broadcast(w_t)
-    g_k, comm = R.uplink(_stack_grads(problem, w_t, x, y, mask), rngs,
-                         GRAD_UPLINK, state=comm)
+    g_own = _stack_grads(problem, w_t, x, y, mask)
+    g_k, comm = R.uplink(g_own, rngs, GRAD_UPLINK, state=comm)
     g_global = R.broadcast(R.wsum(dweight, g_k))
     w_k, _ = jax.vmap(
         partial(_client_lbfgs, problem, hp, w_t, g_global)
-    )(x, y, mask, rngs)
+    )(x, y, mask, rngs, g_own)
     w_k, comm = R.uplink(w_k, rngs, DELTA_UPLINK, anchor=w_t, state=comm)
     new_params = R.wsum(pweight, w_k, anchor=w_t)
     parts = _metric_parts(problem, R, w_t, g_global, _nan_stats(x.shape[0]),
@@ -1186,7 +1212,9 @@ def make_round_fn(algo: str, problem: FLProblem, hp: AlgoHParams,
                   channel: "CommChannel | str | None" = None,
                   faults: "FaultPlan | None" = None,
                   async_cfg: "AsyncConfig | None" = None):
-    """Return a jittable round(state) -> (state, RoundMetrics).
+    """Return a jittable round(state) -> (state, RoundMetrics), or
+    round(state, frozen) for a problem with a frozen operand
+    (``FLProblem.frozen``: pass the operand's value on every call).
 
     Single-process runtime: the K stacked clients are vmapped. The distributed
     runtime with identical numerics is core/sharded.py::make_sharded_round_fn.
@@ -1202,6 +1230,16 @@ def make_round_fn(algo: str, problem: FLProblem, hp: AlgoHParams,
     """
     if algo not in ALGORITHMS:
         raise ValueError(f"unknown algorithm {algo!r}; choose from {ALGORITHMS}")
+    if problem.frozen is not None:
+        # the frozen operand (FLProblem.frozen) is an argument of the round:
+        # the round over a problem that closes over it is built while the
+        # caller's jit traces, so the operand enters as a parameter and no
+        # copy of it is compiled in
+        def frozen_round_fn(state: ServerState, frozen):
+            return make_round_fn(algo, problem.bind(frozen), hp, channel,
+                                 faults, async_cfg)(state)
+
+        return frozen_round_fn
     # resolve the AA and local-trajectory implementations once for this
     # runtime, so the client bodies see a concrete "tree"/"pallas" (never
     # "auto") and ineligible problems/algos fall back before tracing

@@ -128,7 +128,10 @@ def make_chunk_runner(
 ):
     """Compile ``chunk`` rounds of ``round_fn`` into one donated jit.
 
-    Returns ``runner(state, n_live) -> (state, done, metrics, rel, live)``:
+    Returns ``runner(state, n_live, *frozen) -> (state, done, metrics, rel,
+    live)``; ``frozen`` is the frozen operand of a round built as
+    ``round(state, frozen)`` (make_round_fn over a problem with
+    ``FLProblem.frozen``), passed through to every round and never donated:
       state   — after min(n_live, first-stop) rounds; the INPUT state buffers
                 are donated (consumed) when ``donate``;
       done    — scalar bool: a stop criterion fired inside the chunk;
@@ -160,12 +163,12 @@ def make_chunk_runner(
         jnp.maximum(tm.tree_norm(w_star), 1e-30) if w_star is not None else None
     )
 
-    def chunk_fn(state, n_live):
+    def chunk_fn(state, n_live, *frozen):
         def step(carry, i):
             s, done = carry
             # unconditional round + select (NOT lax.cond) — see module
             # docstring: this keeps the chunk bit-exact with the loop
-            new_s, m = round_fn(s)
+            new_s, m = round_fn(s, *frozen)
             with jax.named_scope("fl.stop_check"):
                 if w_star is not None:
                     rel = (tm.tree_norm(tm.tree_sub(new_s.params, w_star))
@@ -206,6 +209,7 @@ def run_rounds(
     donate: bool = True,
     runner: Callable | None = None,
     tap: Callable | None = None,
+    frozen: Pytree | None = None,
     sinks=(),
     run_info: "dict | None" = None,
     trace_capture=None,
@@ -229,6 +233,10 @@ def run_rounds(
     compile time). It MUST have been built from the same ``round_fn`` with
     the same chunk/stop configuration (incl. ``tap``); when omitted, one is
     built here.
+
+    ``frozen`` — the frozen operand of a round built as
+    ``round(state, frozen)`` (make_round_fn over a problem with
+    ``FLProblem.frozen``): every chunk passes it to the runner.
 
     Telemetry (repro/obs — every hook is optional and None/() by default):
       tap           — live in-chunk callback, compiled into the runner (see
@@ -264,6 +272,7 @@ def run_rounds(
             round_fn, chunk, w_star=w_star, stop_rel_error=stop_rel_error,
             stop_grad_norm=stop_grad_norm, donate=donate, tap=tap,
         )
+    operands = () if frozen is None else (frozen,)
     sinks = list(sinks)
     for s in sinks:
         s.open({
@@ -294,7 +303,7 @@ def run_rounds(
                     step_num=start_round // chunk + len(chunk_compiles)):
                 t0 = time.perf_counter()
                 with _span("fl.engine.dispatch"):
-                    state, *out = runner(state, np.int32(n_live))
+                    state, *out = runner(state, np.int32(n_live), *operands)
                 # waiting apart from the fetch adds no sync: device_get would
                 # block on the same results
                 with _span("fl.engine.wait"):

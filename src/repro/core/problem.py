@@ -89,6 +89,14 @@ class FLProblem:
     #: (AlgoHParams.local_impl="pallas"); models that cannot (MLP, decoder)
     #: leave it None and keep the autodiff path.
     linear_design: "Callable[[ClientBatch], LinearDesign] | None" = None
+    #: optional frozen operand: weights the loss reads and no algorithm
+    #: updates (an LM's base under low-rank adapters, core/lm.py). ``loss``
+    #: reads ``frozen``; ``bind(value)`` gives the same problem with the
+    #: loss reading ``value`` instead, as a closure (its ``frozen`` None).
+    #: make_round_fn then builds ``round(state, frozen)``, so the operand is
+    #: an argument of the compiled round and never a constant in it.
+    frozen: Pytree | None = None
+    bind: "Callable[[Pytree], FLProblem] | None" = None
 
     # ---- single-client oracles -------------------------------------------
     def grad(self, params: Pytree, batch: ClientBatch) -> Pytree:

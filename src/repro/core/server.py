@@ -10,6 +10,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import time
+from functools import partial
 from typing import Any, Callable
 
 import jax
@@ -288,7 +289,7 @@ def run_federated(
 
         state, trace = engine.run_rounds(
             round_fn, state, max(0, num_rounds - start_round), chunk=chunk,
-            w_star=w_star,
+            w_star=w_star, frozen=problem.frozen,
             stop_rel_error=stop_rel_error, stop_grad_norm=stop_grad_norm,
             sinks=sinks, run_info=run_info, trace_capture=trace_capture,
             tap=tap, start_round=start_round, checkpoint=ckpt_mgr,
@@ -313,6 +314,8 @@ def run_federated(
         )
 
     round_fn = jax.jit(round_fn)
+    if problem.frozen is not None:
+        round_fn = partial(round_fn, frozen=problem.frozen)
     w_star_norm = None
     rel_fn = None
     if w_star is not None:

@@ -227,6 +227,10 @@ def make_sharded_round_fn(algo: str, problem: FLProblem, hp: AlgoHParams,
     """
     if algo not in ALGORITHMS:
         raise ValueError(f"unknown algorithm {algo!r}; choose from {ALGORITHMS}")
+    if problem.frozen is not None:
+        raise ValueError("a problem with a frozen operand runs on the vmap "
+                         "runtime (make_round_fn); the sharded round closes "
+                         "over its problem")
     # the sharded runtime always takes the leaf-wise tree AA path (leaves may
     # be sharded across the mesh, where the flat-buffer Pallas ravel would
     # force an all-gather) AND the autodiff local-trajectory path:

@@ -20,7 +20,7 @@ import numpy as np
 
 from repro.configs import get_arch
 from repro.core import AlgoHParams, run_federated
-from repro.core.lm import make_lm_clients, make_lm_problem
+from repro.core.lm import make_lm_clients, make_lm_problem, make_lora
 from repro.data import make_lm_tokens
 from repro.models.decoder import build_model
 from repro.utils.compile_cache import use_compile_cache
@@ -141,6 +141,12 @@ def main(argv: "list[str] | None" = None) -> dict:
                     help="with --runtime sharded: use the 2x16x16 two-pod "
                          "mesh instead of the single-pod 16x16 (requires "
                          "enough devices, e.g. the dryrun host-device env)")
+    ap.add_argument("--lora-rank", type=int, default=0,
+                    help="train low-rank adapters (alpha = 2·rank) on every "
+                         "linear map of every layer over a frozen base, as "
+                         "one flat vector (core/lm.py), instead of the "
+                         "whole model; the base is an operand of the "
+                         "compiled round. 0 = train the whole model")
     ap.add_argument("--out", default="")
     # -- preemption-tolerant checkpointing (repro/checkpoint) ------------
     ap.add_argument("--checkpoint-dir", default="",
@@ -213,7 +219,12 @@ def main(argv: "list[str] | None" = None) -> dict:
         args.clients * args.docs_per_client, args.seq_len, cfg.vocab_size
     )
     clients = make_lm_clients(toks, args.clients)
-    problem = make_lm_problem(model, clients)
+    if args.lora_rank > 0:
+        base = jax.jit(model.init)(jax.random.PRNGKey(0))
+        lora = make_lora(base, args.lora_rank, 2 * args.lora_rank)
+        problem = make_lm_problem(model, clients, base, lora)
+    else:
+        problem = make_lm_problem(model, clients)
 
     from repro.comm import make_channel
     from repro.core.anderson import AAConfig

@@ -2,7 +2,11 @@
 
 Layer stacks are scanned (``jax.lax.scan``) so HLO is depth-independent; the
 hybrid (Zamba2) family uses a group-scan: scan over groups of
-(period−1 mamba layers + one weight-TIED shared attention/MLP block).
+(period−1 mamba layers + one weight-TIED shared attention/MLP block). A
+config with ``layer_types`` (granite-4.0-h) is a stack driven by that
+pattern: one scan per run of layers of the same kind, each layer a mixer
+(Mamba-2 or attention) and its own SwiGLU MLP, with the residual stream in
+float32 whatever the weights' dtype.
 
 API (all pure functions, built by ``build_model(cfg, sh)``):
   init(rng)                        -> params
@@ -75,6 +79,37 @@ def _ssm_block(p, h, cfg, sh, state=None, ssd_fn=None):
     return h + y, new_state
 
 
+def _pattern_block(kind, p, h, cfg, sh, positions, window, cache=None,
+                   ssd_fn=None):
+    """One layer of a ``layer_types`` stack (granite-4.0-h):
+    h += m·mixer(norm(h)); h += m·mlp(norm(h)), m the residual multiplier.
+    Returns (h, the mixer's new cache or final state)."""
+    m = cfg.residual_multiplier
+    x = Lyr.rms_norm(h, p["norm"], cfg.norm_eps)
+    if kind == "mamba":
+        y, new_cache = Lyr.mamba_forward(p["mixer"], x, cfg, sh, state=cache,
+                                         ssd_fn=ssd_fn)
+    else:
+        y, new_cache = Lyr.attention(p["attn"], x, cfg, sh, positions,
+                                     cache=cache, window=window)
+    h = h + m * y
+    h = h + m * Lyr.mlp(p["mlp"], Lyr.rms_norm(h, p["mlp_norm"], cfg.norm_eps),
+                        sh)
+    return h, new_cache
+
+
+def pattern_groups(cfg) -> list:
+    """The runs of one kind in ``cfg.layer_types``: [(kind, layers), ...];
+    the params hold one layer-stacked group per run (``params["groups"]``)."""
+    groups = []
+    for kind in cfg.layer_types:
+        if groups and groups[-1][0] == kind:
+            groups[-1] = (kind, groups[-1][1] + 1)
+        else:
+            groups.append((kind, 1))
+    return groups
+
+
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
@@ -116,6 +151,20 @@ def _ssm_layer_init(cfg, dtype):
     return one
 
 
+def _pattern_layer_init(cfg, kind, dtype):
+    def one(rng):
+        k1, k2 = jax.random.split(rng)
+        mixer = ({"mixer": Lyr.mamba_init(k1, cfg, dtype)} if kind == "mamba"
+                 else {"attn": Lyr.attn_init(k1, cfg, dtype)})
+        return {
+            "norm": jnp.ones((cfg.d_model,), dtype),
+            **mixer,
+            "mlp_norm": jnp.ones((cfg.d_model,), dtype),
+            "mlp": Lyr.mlp_init(k2, cfg.d_model, cfg.d_ff, dtype),
+        }
+    return one
+
+
 def _hybrid_counts(cfg):
     """Zamba2 pattern: every ``period``-th block is the shared attn block.
     total = num_layers; n_shared = L // period; mamba fills the rest."""
@@ -139,8 +188,12 @@ def build_model(cfg: ArchConfig, sh: Sharder | None = None, ssd_fn=None,
     sh = sh or Sharder()
     dtype = jnp.dtype(cfg.dtype)
     V, d, L = cfg.eff_vocab, cfg.d_model, cfg.num_layers
-    fam = cfg.family
+    fam = "pattern" if cfg.layer_types else cfg.family
     window = cfg.sliding_window
+    # the residual stream's dtype: the weights' for every family but the
+    # pattern stack, which keeps it in float32
+    act_dtype = jnp.float32 if fam == "pattern" else dtype
+    groups = pattern_groups(cfg)
 
     # ------------------------------ init ------------------------------
     def init(rng: jax.Array) -> Pytree:
@@ -157,6 +210,12 @@ def build_model(cfg: ArchConfig, sh: Sharder | None = None, ssd_fn=None,
             params["blocks"] = _stacked_init(_moe_layer_init(cfg, dtype), ks[2], L)
         elif fam == "ssm":
             params["blocks"] = _stacked_init(_ssm_layer_init(cfg, dtype), ks[2], L)
+        elif fam == "pattern":
+            params["groups"] = [
+                _stacked_init(_pattern_layer_init(cfg, kind, dtype), k, n)
+                for (kind, n), k in zip(
+                    groups, jax.random.split(ks[2], len(groups)))
+            ]
         else:  # hybrid
             n_groups, group, trailing = _hybrid_counts(cfg)
             k_m, k_t, k_s = jax.random.split(ks[2], 3)
@@ -172,7 +231,9 @@ def build_model(cfg: ArchConfig, sh: Sharder | None = None, ssd_fn=None,
 
     # --------------------------- embedding ---------------------------
     def embed_tokens(params, tokens, embeds):
-        h = params["embed"][tokens] * jnp.asarray(jnp.sqrt(d), dtype)
+        mult = cfg.embedding_multiplier or jnp.sqrt(d)
+        h = (params["embed"][tokens].astype(act_dtype)
+             * jnp.asarray(mult, act_dtype))
         if embeds is not None:
             # modality frontend stub: precomputed embeddings overwrite the
             # first `frontend_tokens` positions (vlm patches / audio frames)
@@ -180,10 +241,15 @@ def build_model(cfg: ArchConfig, sh: Sharder | None = None, ssd_fn=None,
             h = jnp.concatenate([embeds.astype(h.dtype), h[:, Pn:]], axis=1)
         return sh(h, "batch", None, None)
 
+    def scale_logits(logits):
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
+        return logits
+
     def unembed(params, h):
-        h = Lyr.rms_norm(h, params["final_norm"])
+        h = Lyr.rms_norm(h, params["final_norm"], cfg.norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        logits = h @ head
+        logits = scale_logits(h @ head)
         return sh(logits, "batch", None, "vocab")
 
     def unembed_last(params, h):
@@ -232,6 +298,14 @@ def build_model(cfg: ArchConfig, sh: Sharder | None = None, ssd_fn=None,
                 lambda p, hh: _ssm_block(p, hh, cfg, sh, ssd_fn=ssd_fn)[0],
                 params["blocks"], h,
             )
+        elif fam == "pattern":
+            for (kind, _), gp in zip(groups, params["groups"]):
+                h, _ = _scan_blocks(
+                    lambda p, hh, kind=kind: _pattern_block(
+                        kind, p, hh, cfg, sh, positions, window,
+                        ssd_fn=ssd_fn)[0],
+                    gp, h,
+                )
         else:  # hybrid group scan
             n_groups, group, trailing = _hybrid_counts(cfg)
             gshape = jax.tree.map(
@@ -284,7 +358,7 @@ def build_model(cfg: ArchConfig, sh: Sharder | None = None, ssd_fn=None,
 
         def body(carry, xs):
             hb, tb, mb = xs                      # [B, c, ...]
-            lg = (hb @ head).astype(jnp.float32)
+            lg = scale_logits((hb @ head).astype(jnp.float32))
             lg = sh(lg, "batch", None, "vocab")
             if pad_mask is not None:
                 lg = jnp.where(pad_mask[None, None, :], -1e30, lg)
@@ -306,7 +380,7 @@ def build_model(cfg: ArchConfig, sh: Sharder | None = None, ssd_fn=None,
         chunked over the sequence (logits never fully materialized)."""
         tokens = batch["tokens"]
         h, aux = forward_hidden(params, tokens, batch.get("embeds"))
-        h = Lyr.rms_norm(h, params["final_norm"])[:, :-1]
+        h = Lyr.rms_norm(h, params["final_norm"], cfg.norm_eps)[:, :-1]
         tgt = tokens[:, 1:]
         mask = batch.get("loss_mask")
         mask = (jnp.ones(tgt.shape, jnp.float32) if mask is None
@@ -331,6 +405,13 @@ def build_model(cfg: ArchConfig, sh: Sharder | None = None, ssd_fn=None,
             def one(_):
                 return Lyr.init_ssm_state(cfg, batch, dtype)
             return jax.vmap(one)(jnp.arange(L))
+        if fam == "pattern":
+            def one(kind):
+                if kind == "mamba":
+                    return lambda _: Lyr.init_ssm_state(cfg, batch, act_dtype)
+                return lambda _: Lyr.init_kv_cache(cfg, batch, cache_len,
+                                                   act_dtype)
+            return [jax.vmap(one(kind))(jnp.arange(n)) for kind, n in groups]
         # hybrid: mamba states + shared-block KV caches (one per application)
         n_groups, group, trailing = _hybrid_counts(cfg)
         m_states = jax.vmap(lambda _: Lyr.init_ssm_state(cfg, batch, dtype))(
@@ -369,6 +450,15 @@ def build_model(cfg: ArchConfig, sh: Sharder | None = None, ssd_fn=None,
                 hh, ns = _ssm_block(p, hh, cfg, sh, state=st)
                 return hh, ns
             h, new_caches = jax.lax.scan(body, h, (params["blocks"], caches))
+        elif fam == "pattern":
+            new_caches = []
+            for (kind, _), gp, gc in zip(groups, params["groups"], caches):
+                def body(hh, xs, kind=kind):
+                    p, c = xs
+                    return _pattern_block(kind, p, hh, cfg, sh, pos, window,
+                                          cache=c)
+                h, nc = jax.lax.scan(body, h, (gp, gc))
+                new_caches.append(nc)
         else:  # hybrid
             n_groups, group, trailing = _hybrid_counts(cfg)
             gparams = jax.tree.map(
@@ -472,6 +562,31 @@ def build_model(cfg: ArchConfig, sh: Sharder | None = None, ssd_fn=None,
                 hh2, st = _ssm_block(p, hh, cfg, sh, ssd_fn=ssd_fn)
                 return hh2, st
             h, caches = jax.lax.scan(body, h, params["blocks"])
+        elif fam == "pattern":
+            caches = []
+            for (kind, n), gp in zip(groups, params["groups"]):
+                if kind == "mamba":
+                    def body(hh, p):
+                        return _pattern_block("mamba", p, hh, cfg, sh,
+                                              positions, window, ssd_fn=ssd_fn)
+                    h, states = jax.lax.scan(body, h, gp)
+                    caches.append(states)
+                    continue
+
+                def body(hh, p):
+                    hn = Lyr.rms_norm(hh, p["norm"], cfg.norm_eps)
+                    hd = cfg.resolved_head_dim
+                    k = Lyr.linear(p["attn"], "wk", hn).reshape(
+                        B, S, cfg.eff_kv_heads, hd)
+                    v = Lyr.linear(p["attn"], "wv", hn).reshape(
+                        B, S, cfg.eff_kv_heads, hd)
+                    if cfg.position_embedding == "rope":
+                        k = Lyr.apply_rope(k, positions, cfg.rope_theta)
+                    hh, _ = _pattern_block("attention", p, hh, cfg, sh,
+                                           positions, window)
+                    return hh, {"k": k, "v": v}
+                h, kvs = jax.lax.scan(body, h, gp)
+                caches.append(pad_kv(kvs, n))
         else:  # hybrid
             n_groups, group, trailing = _hybrid_counts(cfg)
             gshape = jax.tree.map(

@@ -98,6 +98,18 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.reshape(x.shape).astype(x.dtype)
 
 
+def linear(p: dict, name: str, x: jax.Array) -> jax.Array:
+    """``x @ p[name]``, plus the low-rank adapter ``(x @ A) @ B`` when the
+    params carry one under ``name + "_lora"`` (core/lm.py merges an adapter
+    into a frozen base that way; B arrives already scaled by alpha/rank)."""
+    y = x @ p[name]
+    ab = p.get(name + "_lora")
+    if ab is not None:
+        a, b = ab
+        y = y + (x @ a) @ b
+    return y
+
+
 def dense_init(rng, shape, in_axis_size, dtype):
     scale = 1.0 / jnp.sqrt(jnp.asarray(in_axis_size, jnp.float32))
     return (jax.random.normal(rng, shape, jnp.float32) * scale).astype(dtype)
@@ -162,7 +174,16 @@ def _attn_scores_mask(q_pos, k_pos, window: int):
     return m
 
 
-def _attention_blocked(q5, k, v, positions, window: int, block: int = 512):
+def attention_scale(cfg):
+    """The softmax scale: the config's ``attention_multiplier`` (granite's
+    muP scale, 1/head_dim there) or the usual 1/sqrt(head_dim)."""
+    if cfg.attention_multiplier:
+        return cfg.attention_multiplier
+    return 1.0 / jnp.sqrt(jnp.asarray(cfg.resolved_head_dim, jnp.float32))
+
+
+def _attention_blocked(q5, k, v, positions, window: int, scale,
+                       block: int = 512):
     """Flash-style blocked attention in pure XLA (§Perf H3 iter 2).
 
     q5: [B, Sq, KV, G, hd] (grouped layout); k, v: [B, Sk, KV, hd].
@@ -178,7 +199,6 @@ def _attention_blocked(q5, k, v, positions, window: int, block: int = 512):
     block = min(block, Sk)
     nb = Sk // block
     assert Sk % block == 0
-    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
     q32 = q5.astype(jnp.float32)
 
     def body(carry, kb):
@@ -221,14 +241,27 @@ def attention(
     B, S, d = x.shape
     hd = cfg.resolved_head_dim
     H, KV = cfg.eff_heads, cfg.eff_kv_heads
-    q = (x @ p["wq"]).reshape(B, S, H, hd)
-    k = (x @ p["wk"]).reshape(B, S, KV, hd)
-    v = (x @ p["wv"]).reshape(B, S, KV, hd)
+    q = linear(p, "wq", x).reshape(B, S, H, hd)
+    k = linear(p, "wk", x).reshape(B, S, KV, hd)
+    v = linear(p, "wv", x).reshape(B, S, KV, hd)
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"])
         k = rms_norm(k, p["k_norm"])
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.position_embedding == "rope":
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("fl.lm_attention"):
+        out, new_cache = _attention_core(q, k, v, x.dtype, cfg, sh, positions,
+                                         cache, window)
+    return linear(p, "wo", out), new_cache
+
+
+def _attention_core(q, k, v, dtype, cfg, sh, positions, cache, window):
+    """softmax(q kᵀ · scale) v over the causal (or windowed) keys, with the
+    decode path's KV ring buffer. Returns ([B, S, H·hd] before o_proj,
+    new_cache)."""
+    B, S, H, hd = q.shape
+    KV = k.shape[2]
     q = sh(q, "batch", None, "heads", None)
     k = sh(k, "batch", None, "kv_heads", None)
     v = sh(v, "batch", None, "kv_heads", None)
@@ -250,8 +283,8 @@ def attention(
             new_cache = {"k": k_all, "v": v_all, "k_scale": k_sc,
                          "v_scale": v_sc, "pos": k_pos, "idx": cache["idx"] + 1}
             k_use = k_all.astype(jnp.float32) * k_sc
-            v_use = (v_all.astype(jnp.float32) * v_sc).astype(x.dtype)
-            k_use = k_use.astype(x.dtype)
+            v_use = (v_all.astype(jnp.float32) * v_sc).astype(dtype)
+            k_use = k_use.astype(dtype)
         else:
             k_all = cache["k"].at[:, slot].set(k[:, 0])
             v_all = cache["v"].at[:, slot].set(v[:, 0])
@@ -263,7 +296,7 @@ def attention(
         new_cache = None
         k_use, v_use, k_positions = k, v, positions
 
-    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+    scale = attention_scale(cfg)
     mask = _attn_scores_mask(positions, k_positions, window)   # [B,Sq,Sk]
     if cache is not None:
         # never-written slots carry pos = -1; exclude them
@@ -278,15 +311,14 @@ def attention(
         if cache is None and S >= 1024 and S % 512 == 0:
             # §Perf H3 iter 2: flash-style blocked path for long train/
             # prefill sequences — scores never hit HBM
-            out = _attention_blocked(q5, k_use, v_use, positions, window)
-            out = out.reshape(B, S, H * hd)
-            return out @ p["wo"], new_cache
+            out = _attention_blocked(q5, k_use, v_use, positions, window,
+                                     scale)
+            return out.reshape(B, S, H * hd), new_cache
         logits = jnp.einsum("bqkgd,bskd->bkgqs", q5, k_use).astype(jnp.float32)
         logits = jnp.where(mask[:, None, None], logits * scale, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
+        probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
         out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v_use)
-        out = out.reshape(B, S, H * hd)
-        return out @ p["wo"], new_cache
+        return out.reshape(B, S, H * hd), new_cache
 
     # gather fallback: map each q head to its kv head via the TRUE counts so
     # padded q/kv heads never change the mapping — padded kv heads are never
@@ -302,17 +334,15 @@ def attention(
         # §Perf H3 iter 2 (gather-mode variant): blocked attention with the
         # gathered kv treated as MHA (KV=H, G=1)
         out = _attention_blocked(
-            q.reshape(B, S, H, 1, hd), k_use, v_use, positions, window
+            q.reshape(B, S, H, 1, hd), k_use, v_use, positions, window, scale
         )
-        out = out.reshape(B, S, H * hd)
-        return out @ p["wo"], new_cache
+        return out.reshape(B, S, H * hd), new_cache
 
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_use).astype(jnp.float32) * scale
     logits = jnp.where(mask[:, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
+    probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs, v_use)
-    out = out.reshape(B, S, H * hd)
-    return out @ p["wo"], new_cache
+    return out.reshape(B, S, H * hd), new_cache
 
 
 def init_kv_cache(cfg, batch: int, cache_len: int, dtype) -> dict:
@@ -358,9 +388,9 @@ def mlp_init(rng, d: int, f: int, dtype) -> dict:
 
 
 def mlp(p: dict, x: jax.Array, sh: Sharder) -> jax.Array:
-    h = jax.nn.silu(x @ p["wi_gate"]) * (x @ p["wi_up"])
+    h = jax.nn.silu(linear(p, "wi_gate", x)) * linear(p, "wi_up", x)
     h = sh(h, "batch", None, "d_ff")
-    return h @ p["wo"]
+    return linear(p, "wo", h)
 
 
 # ---------------------------------------------------------------------------
@@ -634,11 +664,11 @@ def mamba_forward(
     """
     B, S, d = x.shape
     di, st, nh, hd = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
-    xz = sh(x @ p["wx"], "batch", None, "ssm_inner")
-    z = sh(x @ p["wz"], "batch", None, "ssm_inner")
-    Bm = x @ p["wB"]
-    Cm = x @ p["wC"]
-    dt_raw = x @ p["wdt"]
+    xz = sh(linear(p, "wx", x), "batch", None, "ssm_inner")
+    z = sh(linear(p, "wz", x), "batch", None, "ssm_inner")
+    Bm = linear(p, "wB", x)
+    Cm = linear(p, "wC", x)
+    dt_raw = linear(p, "wdt", x)
 
     conv_in = jnp.concatenate([xz, Bm, Cm], axis=-1)     # [B,S,di+2st]
     W = cfg.ssm_conv_width
@@ -664,9 +694,10 @@ def mamba_forward(
     Bc32, Cc32 = Bc.astype(jnp.float32), Cc.astype(jnp.float32)
 
     if state is None:
-        y, final_state = _ssd_chunked_scan(
-            xh, dt, A, Bc32, Cc32, min(cfg.ssm_chunk, S), ssd_fn=ssd_fn
-        )
+        with jax.named_scope("fl.lm_ssd"):
+            y, final_state = _ssd_chunked_scan(
+                xh, dt, A, Bc32, Cc32, min(cfg.ssm_chunk, S), ssd_fn=ssd_fn
+            )
         new_state = (
             {"conv": new_conv_state, "ssm": final_state} if new_conv_state is not None
             else {"ssm": final_state}
@@ -683,9 +714,9 @@ def mamba_forward(
     y = y + xh * p["D"][None, None, :, None]
     y = y.reshape(B, S, di).astype(x.dtype)
     y = y * jax.nn.silu(z)                                # gated
-    y = rms_norm(y, p["norm"])
+    y = rms_norm(y, p["norm"], cfg.norm_eps)
     y = sh(y, "batch", None, "ssm_inner")
-    return y @ p["out_proj"], new_state
+    return linear(p, "out_proj", y), new_state
 
 
 def init_ssm_state(cfg, batch: int, dtype) -> dict:

@@ -84,7 +84,7 @@ def param_spec_for_path(path: str, ndim: int, plan: ShardingPlan) -> P:
     ax = plan.axes
     fa = _fsdp_axis(plan)
     name = path.split("/")[-1]
-    stacked = path.startswith(("blocks", "mamba_groups", "mamba_tail"))
+    stacked = path.startswith(("blocks", "mamba_groups", "mamba_tail", "groups"))
     L = (None,) if stacked else ()
 
     def spec(*dims):

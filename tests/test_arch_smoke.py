@@ -122,7 +122,7 @@ class TestDecode:
 
 
 def test_all_archs_registered():
-    assert len(ARCHS) == 10
+    assert len(ARCHS) == 11
     fams = {get_arch(a).family for a in ARCHS}
     assert fams == {"dense", "moe", "vlm", "ssm", "hybrid", "audio"}
 
@@ -137,6 +137,7 @@ def test_param_counts_plausible():
         "granite-20b": 20e9,
         "minicpm-2b": 2.4e9,
         "zamba2-7b": 7e9,
+        "granite-4.0-h-micro": 3e9,
     }
     for name, n in expect.items():
         got = get_arch(name).param_count()
